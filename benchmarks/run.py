@@ -162,6 +162,9 @@ def check_regression(records: dict, baseline_path: str, *, smoke: bool,
 
 
 def main(argv=None) -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes (CI bitrot check, not a measurement)")

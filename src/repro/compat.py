@@ -19,24 +19,10 @@ def shard_map(
     out_specs,
     check_vma: bool | None = None,
 ) -> Callable:
-    """Resolve ``shard_map`` across JAX versions.
-
-    Newer JAX exposes ``jax.shard_map`` with a ``check_vma`` flag; older
-    versions only have ``jax.experimental.shard_map.shard_map`` where the
-    same knob is spelled ``check_rep``.  ``check_vma=None`` means "library
-    default" on either version.
-    """
+    """``jax.shard_map``; ``check_vma=None`` keeps the library default."""
     kwargs: dict[str, Any] = {}
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
     if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    return _shard_map(
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
     )

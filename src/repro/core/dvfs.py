@@ -235,8 +235,9 @@ class RateState(NamedTuple):
 
 
 def rate_state_init() -> RateState:
-    z = jnp.int32(0)
-    return RateState(win=z, cur=z, prev1=z, prev2=z)
+    # one array per leaf: a step that donates the state refuses a buffer
+    # that two of its leaves share
+    return RateState(*(jnp.int32(0) for _ in RateState._fields))
 
 
 def online_vdd_from_chunk_ts(

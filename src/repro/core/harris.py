@@ -30,32 +30,6 @@ DEFAULT_SOBEL = 5
 DEFAULT_WINDOW = 5
 
 
-def _ensure_barrier_batching_rule() -> None:
-    """Backport the (identity) vmap rule for ``optimization_barrier``.
-
-    ``harris_response`` fences its conv region with ``optimization_barrier``
-    (see its docstring), and the pool executors vmap ``detector_step`` over
-    lanes — but the jax pinned here predates the upstream batching rule for
-    the barrier primitive.  The rule is trivially the identity on batch
-    dims (a barrier is semantically transparent), so register it iff absent.
-    """
-    try:
-        from jax._src.interpreters import batching
-        from jax._src.lax import lax as _lax_src
-
-        prim = _lax_src.optimization_barrier_p
-        if prim not in batching.primitive_batchers:
-            def _rule(batched_args, batch_dims, **params):
-                return prim.bind(*batched_args, **params), batch_dims
-
-            batching.primitive_batchers[prim] = _rule
-    except Exception:  # pragma: no cover - newer jax layouts ship the rule
-        pass
-
-
-_ensure_barrier_batching_rule()
-
-
 def _pascal_row(n: int) -> np.ndarray:
     row = np.array([1.0])
     for _ in range(n - 1):

@@ -110,6 +110,7 @@ class PipelineResult:
     scores: np.ndarray          # per-event Harris-LUT score (-inf = filtered)
     kept: np.ndarray            # survived STCF
     tos: np.ndarray             # final surface
+    sae: np.ndarray             # final STCF last-timestamp surface
     lut: np.ndarray             # final Harris LUT
     vdd_trace: np.ndarray       # per-chunk operating voltage
     energy_pj: float            # total dynamic energy (hw model)
@@ -199,8 +200,8 @@ def _chunk_inputs(prep: _Prepared) -> state_mod.ChunkInput:
     )
 
 
-def _finalize(cfg, n_events, vdd_arr, surface, lut, scores, keep, n_kept,
-              *, host_syncs: int) -> PipelineResult:
+def _finalize(cfg, n_events, vdd_arr, surface, sae, lut, scores, keep,
+              n_kept, *, host_syncs: int) -> PipelineResult:
     scores = np.asarray(scores, np.float32).reshape(-1)[:n_events]
     kept = np.asarray(keep, bool).reshape(-1)[:n_events]
     energy_pj, latency_ns = _accounting(np.asarray(n_kept), vdd_arr)
@@ -209,6 +210,7 @@ def _finalize(cfg, n_events, vdd_arr, surface, lut, scores, keep, n_kept,
         scores=scores,
         kept=kept,
         tos=np.asarray(surface),
+        sae=np.asarray(sae),
         lut=np.asarray(lut),
         vdd_trace=vdd_arr,
         energy_pj=energy_pj,
@@ -301,8 +303,9 @@ def run_pipeline(
     fin, outs = scan(state, _chunk_inputs(prep))
     fin, outs = jax.device_get((fin, outs))  # sync #1
     vdd_arr = _vdd_trace(prep, outs.vdd_idx, cfg)
-    return _finalize(cfg, prep.n_events, vdd_arr, fin.surface, fin.lut,
-                     outs.scores, outs.keep, outs.n_kept, host_syncs=1)
+    return _finalize(cfg, prep.n_events, vdd_arr, fin.surface, fin.sae,
+                     fin.lut, outs.scores, outs.keep, outs.n_kept,
+                     host_syncs=1)
 
 
 def run_pipeline_batched(
@@ -344,7 +347,7 @@ def run_pipeline_batched(
         vdd_arr = _vdd_trace(preps[i], outs.vdd_idx[i], cfg)
         results.append(
             _finalize(cfg, preps[i].n_events, vdd_arr, fins.surface[i],
-                      fins.lut[i], outs.scores[i], outs.keep[i],
+                      fins.sae[i], fins.lut[i], outs.scores[i], outs.keep[i],
                       outs.n_kept[i], host_syncs=1)
         )
     return results
@@ -443,6 +446,7 @@ def run_pipeline_reference(
         scores=scores[:n_events],
         kept=kept_all[:n_events],
         tos=np.asarray(surface),
+        sae=np.asarray(sae),
         lut=np.asarray(lut),
         vdd_trace=vdd_arr,
         energy_pj=total_energy_pj,

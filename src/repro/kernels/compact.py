@@ -9,13 +9,15 @@ device*, so the reader thread fetches ``O(cap)`` bytes per slot-lane
 instead of ``O(chunk)``: the near-memory thesis applied to the readout
 path, the same way the macro never ships the dense surface off-chip.
 
-One grid cell per lane; the cell streams its ``(1, E)`` score/keep blocks
-through a sequential ``fori_loop`` carrying the ``(1, cap)`` record
-buffers and a running kept-count — the loop spelling of the oracle's
-cumsum-scatter (``ref.compact_ref``), bit-exact against it by
-construction: writer ``j`` is the j-th kept event in stream order, and
-records past ``cap`` are suppressed (the caller falls back to the dense
-slot it still has — overflow is lossless by design, never a drop).
+One grid cell per block of 8 lanes (the f32 sublane tile); lanes are padded
+to a multiple of 8 and cropped back.  Record ``j`` of every lane in the
+block is extracted at once: the first kept event not yet recorded is the
+row-wise minimum of a masked event-index iota, its score the row-wise max
+of the scores masked to that one-hot column.  ``cap`` such vector passes
+replace the oracle's cumsum-scatter (``ref.compact_ref``) and are bit-exact
+against it: record ``j`` is the j-th kept event in stream order, and
+records past ``cap`` are never extracted (the caller falls back to the
+dense slot it still has — overflow is lossless by design, never a drop).
 
 Unused record slots read ``idx=0, val=-inf`` so a host densify can
 scatter the first ``min(count, cap)`` records into a ``-inf``/``False``
@@ -31,34 +33,36 @@ from jax.experimental import pallas as pl
 
 __all__ = ["compact_slots_call"]
 
+LANE_BLOCK = 8   # lanes per grid cell: the sublane tile of i32/f32 blocks
+
 
 def _compact_kernel(scores_ref, keep_ref, idx_out, val_out, cnt_out, *,
                     n_events: int, cap: int):
-    def body(i, carry):
-        idx, val, n = carry
-        kept = keep_ref[0, i] > 0
-        write = kept & (n < cap)
-        slot = jnp.minimum(n, cap - 1)
-        cur_i = jax.lax.dynamic_slice(idx, (0, slot), (1, 1))[0, 0]
-        cur_v = jax.lax.dynamic_slice(val, (0, slot), (1, 1))[0, 0]
-        new_i = jnp.where(write, i, cur_i).astype(jnp.int32)
-        new_v = jnp.where(write, scores_ref[0, i], cur_v)
-        idx = jax.lax.dynamic_update_slice(
-            idx, new_i.reshape(1, 1), (0, slot)
-        )
-        val = jax.lax.dynamic_update_slice(
-            val, new_v.astype(jnp.float32).reshape(1, 1), (0, slot)
-        )
-        return idx, val, n + kept.astype(jnp.int32)
+    kept = keep_ref[...]                                      # (8, E) 0/1
+    scores = scores_ref[...]
+    ev = jax.lax.broadcasted_iota(jnp.int32, kept.shape, 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, idx_out.shape, 1)
+    neg_inf = jnp.float32(-jnp.inf)
 
-    idx0 = jnp.zeros((1, cap), jnp.int32)
-    val0 = jnp.full((1, cap), -jnp.inf, jnp.float32)
-    idx, val, n = jax.lax.fori_loop(
-        0, n_events, body, (idx0, val0, jnp.int32(0))
+    def body(j, carry):
+        todo, idx, val = carry
+        first = jnp.min(jnp.where(todo > 0, ev, n_events), axis=1,
+                        keepdims=True)                        # (8, 1)
+        hit = ev == first
+        v = jnp.max(jnp.where(hit, scores, neg_inf), axis=1, keepdims=True)
+        put = slot == j
+        idx = jnp.where(put, jnp.where(first < n_events, first, 0), idx)
+        val = jnp.where(put, v, val)
+        return jnp.where(hit, 0, todo), idx, val
+
+    _, idx, val = jax.lax.fori_loop(
+        0, cap, body,
+        (kept, jnp.zeros(idx_out.shape, jnp.int32),
+         jnp.full(val_out.shape, neg_inf, jnp.float32)),
     )
     idx_out[...] = idx
     val_out[...] = val
-    cnt_out[0] = n
+    cnt_out[...] = jnp.sum(kept, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "interpret"))
@@ -69,30 +73,33 @@ def compact_slots_call(
     cap: int,
     interpret: bool,
 ):
-    """Compact ``L`` lane slots at once: one grid cell per lane.
+    """Compact ``L`` lane slots at once: one grid cell per 8 lanes.
 
     Returns ``(idx (L, cap) i32, val (L, cap) f32, count (L,) i32)``;
     ``count`` is the TOTAL kept (it may exceed ``cap`` — that is the
     caller's overflow signal, the records themselves stop at ``cap``).
     """
     l, e = scores.shape
+    lp = -(-l // LANE_BLOCK) * LANE_BLOCK
+    pad = ((0, lp - l), (0, 0))
     kernel = functools.partial(_compact_kernel, n_events=e, cap=cap)
-    return pl.pallas_call(
+    idx, val, cnt = pl.pallas_call(
         kernel,
-        grid=(l,),
+        grid=(lp // LANE_BLOCK,),
         in_specs=[
-            pl.BlockSpec((1, e), lambda i: (i, 0)),
-            pl.BlockSpec((1, e), lambda i: (i, 0)),
+            pl.BlockSpec((LANE_BLOCK, e), lambda i: (i, 0)),
+            pl.BlockSpec((LANE_BLOCK, e), lambda i: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, cap), lambda i: (i, 0)),
-            pl.BlockSpec((1, cap), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
+            pl.BlockSpec((LANE_BLOCK, cap), lambda i: (i, 0)),
+            pl.BlockSpec((LANE_BLOCK, cap), lambda i: (i, 0)),
+            pl.BlockSpec((LANE_BLOCK, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((l, cap), jnp.int32),
-            jax.ShapeDtypeStruct((l, cap), jnp.float32),
-            jax.ShapeDtypeStruct((l,), jnp.int32),
+            jax.ShapeDtypeStruct((lp, cap), jnp.int32),
+            jax.ShapeDtypeStruct((lp, cap), jnp.float32),
+            jax.ShapeDtypeStruct((lp, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(scores, keep.astype(jnp.int32))
+    )(jnp.pad(scores, pad), jnp.pad(keep.astype(jnp.int32), pad))
+    return idx[:l], val[:l], cnt[:l, 0]

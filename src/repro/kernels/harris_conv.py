@@ -8,9 +8,8 @@ work, no gather).  Strip overlap (halo) is read directly from the VMEM-
 resident input, which Pallas allows because the input block is the whole
 array.
 
-For sensors beyond VMEM the wrapper falls back to the jnp oracle (XLA then
-tiles the convs itself); the kernel documents its VMEM budget in
-``vmem_bytes``.
+The kernel documents its VMEM budget in ``vmem_bytes``; the wrapper
+(``ops.harris_response_op``) refuses a sensor beyond it.
 """
 from __future__ import annotations
 

@@ -8,8 +8,8 @@
                          BER -> LUT score) in one kernel, VMEM-resident end
                          to end (``backend="pallas_fused"``; see
                          ``kernels.fused_step``).
-``harris_response_op`` — Pallas Harris when the surface fits VMEM, jnp
-                         fallback otherwise.
+``harris_response_op`` — Pallas Harris; a surface over its VMEM budget
+                         is an error (no kernel falls back to a reference).
 ``compact_slots_op``   — device-side stream compaction of dense ring
                          result slots into kept-corner records
                          (``kernels.compact``; the D2H readout diet for
@@ -196,24 +196,24 @@ def _fused_step_jit(
     patch, th, support, tw, stcf_enabled, inject_ber, interpret,
 ):
     h, w = tos.shape
-    tos_p, _ = _pad_to_tiles(tos)
-    hp, wp = tos_p.shape
-    lut_p = jnp.pad(lut, ((0, hp - h), (0, wp - w)))
-    # SAE: tile-pad then radius-pad, both with _NEVER so out-of-surface
-    # neighbours read as "never fired" (== the oracle's in-bounds mask).
-    sae_p = jnp.pad(
-        sae,
-        ((fused_step.RS, hp - h + fused_step.RS),
-         (fused_step.RS, wp - w + fused_step.RS)),
-        constant_values=_NEVER,
-    )
+    r = (patch - 1) // 2
+    sub, lanes = fused_step.SUBLANES, fused_step.LANES
+    hp = max(-(-h // sub) * sub, fused_step.window_rows(fused_step.RS),
+             fused_step.window_rows(r))
+    wp = -(-w // lanes) * lanes
+    pad = ((0, hp - h), (0, wp - w))
+    tos_p = jnp.pad(tos.astype(jnp.int32), pad)
+    lut_p = jnp.pad(lut, pad)
+    # out-of-surface neighbours read as "never fired" (== the oracle's
+    # in-bounds mask)
+    sae_p = jnp.pad(sae, pad, constant_values=_NEVER)
     ev = jnp.stack(
         [xy[:, 0].astype(jnp.int32), xy[:, 1].astype(jnp.int32),
          ts.astype(jnp.int32), valid.astype(jnp.int32)],
         axis=1,
     )
     if inject_ber:
-        bits_p = jnp.pad(bits, ((0, hp - h), (0, wp - w)))
+        bits_p = jnp.pad(bits, pad)
         ber_arg = jnp.asarray(ber)
     else:
         bits_p, ber_arg = None, None
@@ -222,7 +222,8 @@ def _fused_step_jit(
         patch=patch, th=th, support=support, tw=tw,
         stcf_enabled=stcf_enabled, interpret=interpret,
     )
-    return tos_o[:h, :w], sae_o[:h, :w], keep.astype(bool), scores
+    return (tos_o[:h, :w].astype(tos.dtype), sae_o[:h, :w],
+            keep[0].astype(bool), scores[0])
 
 
 def compact_slots_op(
@@ -288,11 +289,11 @@ def _harris_response_jit(
 ) -> jax.Array:
     h, w = tos.shape
     budget = 16 * 2**20  # one v5e core's VMEM, conservative
-    if harris_conv.vmem_bytes(h, w, sobel_size, window_size) > budget:
-        from repro.core.harris import harris_response
-
-        return harris_response(
-            tos, sobel_size=sobel_size, window_size=window_size, k=k
+    need = harris_conv.vmem_bytes(h, w, sobel_size, window_size)
+    if need > budget:
+        raise ValueError(
+            f"{h}x{w} Harris needs {need} B of VMEM, over the kernel's "
+            f"{budget} B budget"
         )
     return harris_conv.harris_call(
         tos, sobel_size=sobel_size, window_size=window_size, k=k,

@@ -17,10 +17,16 @@ import jax
 __all__ = ["make_production_mesh", "make_local_mesh", "HW"]
 
 
+def _auto(n: int) -> tuple:
+    """``Auto`` axis types: ``jax.make_mesh`` defaults to ``Explicit`` axes,
+    which ``with_sharding_constraint`` (``meshctx.shard_act``) rejects."""
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
@@ -28,7 +34,8 @@ def make_local_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // data))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 class HW:

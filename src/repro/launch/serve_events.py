@@ -83,6 +83,7 @@ import numpy as np
 from repro import obs as obs_mod
 from repro.core import pipeline
 from repro.events import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import DetectorPool
 
 # The console rendering of a metrics emission: the pipeline/coalescing/
@@ -113,6 +114,7 @@ def _attach_sinks(pool, metrics_out):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--sessions", type=int, default=4)
     ap.add_argument("--duration-us", type=int, default=40_000)
@@ -184,7 +186,7 @@ def main(argv=None):
     ap.add_argument("--dvfs", action="store_true",
                     help="online (in-step) DVFS instead of fixed 1.2 V")
     ap.add_argument("--backend", default="jnp",
-                    choices=("jnp", "pallas_nmc", "pallas_batched"))
+                    choices=pipeline.BACKENDS)
     args = ap.parse_args(argv)
 
     cfg = pipeline.PipelineConfig(

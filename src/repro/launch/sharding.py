@@ -95,10 +95,12 @@ def pinned_host_sharding(device) -> Optional[SingleDeviceSharding]:
     """
     if getattr(device, "platform", "cpu") == "cpu":
         return None
-    try:
-        kinds = {m.kind for m in device.addressable_memories()}
-    except Exception:
+    memories = getattr(device, "addressable_memories", None)
+    if memories is None:
         return None
+    # a runtime error from the probe propagates: it is a fault, not an
+    # answer of "no pinned memory"
+    kinds = {m.kind for m in memories()}
     if "pinned_host" not in kinds:
         return None
     return SingleDeviceSharding(device, memory_kind="pinned_host")
@@ -129,6 +131,10 @@ class HostStager:
             raise ValueError("depth must be >= 1")
         self.device = jax.devices()[0] if device is None else device
         self._pinned = pinned_host_sharding(self.device)
+        # the second hop names the device memory space: a plain device
+        # target refuses a buffer that lives in pinned_host memory
+        self._on_device = SingleDeviceSharding(self.device,
+                                               memory_kind="device")
         self.depth = int(depth)
         self._inflight = collections.deque(maxlen=self.depth)
         self.uploads = 0          # put() calls routed through this stager
@@ -151,7 +157,7 @@ class HostStager:
         # the second-hop copy may still be reading these locked pages when
         # the caller moves on to stage the next block
         self._inflight.append(staged)
-        return jax.device_put(staged, self.device)
+        return jax.device_put(staged, self._on_device)
 
 
 def make_rules(cfg: ModelConfig, mesh: Mesh, *, fsdp: bool = True,

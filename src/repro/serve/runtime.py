@@ -102,7 +102,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import PartitionSpec as P, SingleDeviceSharding
 
 from repro import compat
 from repro import obs as obs_mod
@@ -376,13 +376,19 @@ class PoolRuntime:
             if self._mesh is not None else capacity
         )
 
-        self._states = jax.tree.map(
+        # Unsharded pools commit every executor-carried array to one
+        # device sharding, as the sharded path commits to its mesh: staged
+        # uploads arrive committed, so carried state must never flip
+        # between committed and uncommitted (each flip is a new executable).
+        self._device = (
+            SingleDeviceSharding(jax.devices()[0], memory_kind="device")
+            if self._mesh is None else None
+        )
+        self._states = self._place(jax.tree.map(
             lambda *xs: jnp.stack(xs),
             *[state_mod.detector_init(cfg, seed=seed + i)
               for i in range(self._phys)],
-        )
-        if self._mesh is not None:
-            self._states = sharding_mod.lane_put(self._mesh, self._states, 0)
+        ))
         self._active = np.zeros((self._phys,), bool)
         self._lanes: list[Optional[_Lane]] = [None] * self._phys
 
@@ -689,7 +695,8 @@ class PoolRuntime:
             )
             return jax.jit(block, out_shardings=out_shardings,
                            donate_argnames=donate)
-        return jax.jit(block, donate_argnames=donate)
+        return jax.jit(block, out_shardings=self._device,
+                       donate_argnames=donate)
 
     def _build_single_executor(self, bucket: int):
         """Jitted 1-round block: the H2D fast path for sparse arrivals.
@@ -728,7 +735,8 @@ class PoolRuntime:
             )
             return jax.jit(single, out_shardings=out_shardings,
                            donate_argnames=donate)
-        return jax.jit(single, donate_argnames=donate)
+        return jax.jit(single, out_shardings=self._device,
+                       donate_argnames=donate)
 
     @staticmethod
     def _chunk_spec_template():
@@ -746,8 +754,8 @@ class PoolRuntime:
         else:
             ring = state_mod.ring_init(self._ring_rounds, self._phys, bucket)
         if self._mesh is not None:
-            ring = sharding_mod.lane_put(self._mesh, ring, 1)
-        return ring
+            return sharding_mod.lane_put(self._mesh, ring, 1)
+        return jax.device_put(ring, self._device)
 
     def _ring_push_fn(self, bucket: int):
         """The executor's ring-push callable, chosen once at build time so
@@ -784,17 +792,14 @@ class PoolRuntime:
 
     def _reset_ring(self, ring: state_mod.RingState) -> state_mod.RingState:
         """Mark a drained ring empty (count/dropped -> 0) without touching
-        its data buffers.  The zeroed scalars must match the old scalars'
-        commitment: sharded rings are committed NamedSharding arrays (a bare
-        jnp scalar would flip the executor's cache key and recompile),
-        unsharded rings are uncommitted (a device_put scalar would do the
-        same flip)."""
-        zero_c = jnp.int32(0)
-        zero_d = jnp.int32(0)
-        if self._mesh is not None:
-            zero_c = jax.device_put(zero_c, ring.count.sharding)
-            zero_d = jax.device_put(zero_d, ring.dropped.sharding)
-        return ring._replace(count=zero_c, dropped=zero_d)
+        its data buffers.  The zeroed scalars are committed like the old
+        ones (a bare jnp scalar would flip the executor's cache key and
+        recompile), and each is its own device buffer: the executor donates
+        the ring, and the runtime refuses one buffer in two donated leaves."""
+        return ring._replace(
+            count=jax.device_put(np.int32(0), ring.count.sharding),
+            dropped=jax.device_put(np.int32(0), ring.dropped.sharding),
+        )
 
     # -- membership ---------------------------------------------------------
 
@@ -1647,12 +1652,13 @@ class PoolRuntime:
             raise KeyError(f"lane {lane} is not an active session")
 
     def _place(self, states):
-        """Pin the lane sharding after a per-lane host update (`_vreset` /
-        `_vrebase` infer their own output sharding, which on a 1-wide mesh
-        can canonicalize away the NamedSharding and flip the executor's
-        cache key).  No-op (no copy) when already placed, or unsharded."""
+        """Pin the pool's sharding after a per-lane host update (`_vreset` /
+        `_vrebase` infer their own output sharding, which can canonicalize
+        away the NamedSharding on a 1-wide mesh, or the memory kind on one
+        device, and flip the executor's cache key).  No copy when already
+        placed."""
         if self._mesh is None:
-            return states
+            return jax.device_put(states, self._device)
         return sharding_mod.lane_put(self._mesh, states, 0)
 
     def _pump_bucket(self, bucket: int, q: collections.deque,

@@ -102,6 +102,15 @@ def test_real_state_donation_decision_matches_backend():
     pool.close()
 
 
+def test_fresh_state_leaves_own_their_buffers():
+    """A donating step (StreamingDetector, run_pipeline's scan) hands every
+    state leaf to XLA; the runtime refuses a buffer shared by two donated
+    leaves, so a fresh state may not reuse one array for several leaves."""
+    leaves = jax.tree.leaves(state_mod.detector_init(CFG))
+    ptrs = [leaf.unsafe_buffer_pointer() for leaf in leaves]
+    assert len(set(ptrs)) == len(ptrs)
+
+
 def test_disconnect_mid_migration_discards_staged_state():
     """Regression (ISSUE 5 satellite): ``disconnect()`` of a lane whose
     migration is staged (snapshot taken, restore pending) must discard the

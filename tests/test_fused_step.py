@@ -118,9 +118,9 @@ def test_fused_op_stcf_disabled():
 
 
 # ---------------------------------------------------------------------------
-# Tile geometry: patches straddling the 128x128 Pallas tile boundary and the
-# surface edge (centre in tile A, halo in tile B; odd sizes forcing the
-# padded tail tiles of ``_pad_to_tiles``).
+# Window geometry: patches straddling 128-pixel boundaries, the kernel's
+# 8-row window blocks and the surface edge (odd sizes force the padded tail
+# rows and columns of ``ops.fused_step_op``).
 # ---------------------------------------------------------------------------
 
 
@@ -155,8 +155,8 @@ def test_fused_tile_straddle_and_edges(patch, hw):
 
 
 def test_fused_boundary_events_cross_tile_halo():
-    """An event at x=127 decrements pixels in the x=128 tile and vice
-    versa — the halo write must land in the neighbouring output tile."""
+    """An event at x=127 decrements pixels at x=128 and up, and vice
+    versa — the patch write must land across the 128-column boundary."""
     h, w, patch = 256, 256, 7
     tos = jnp.full((h, w), 255, jnp.uint8)
     sae = jnp.full((h, w), stcf_mod._NEVER, jnp.int32)

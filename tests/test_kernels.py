@@ -109,3 +109,11 @@ def test_env_flip_takes_effect_per_call(rng, monkeypatch):
     # the interpret call above produced the oracle result.
     assert ops.default_interpret() is False
     np.testing.assert_array_equal(np.asarray(a), np.asarray(t0))
+
+
+def test_harris_op_refuses_over_vmem_budget():
+    """A surface past the kernel's VMEM budget is an error, never a silent
+    fallback to the jnp oracle."""
+    t = jnp.zeros((720, 1280), jnp.uint8)
+    with pytest.raises(ValueError, match="VMEM"):
+        ops.harris_response_op(t)

@@ -2,6 +2,7 @@
 dispatch path, single-process (1-device mesh) and multi-device (subprocess
 with 8 forced host devices — kept out-of-process so the main pytest run
 stays on 1 device)."""
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -17,13 +18,17 @@ from repro.meshctx import use_mesh_rules
 from repro.models.common import init_dense
 from repro.models.mlp import moe_apply, moe_apply_a2a, moe_spec
 
+# jax.make_mesh defaults to Explicit axes; the mesh-rules path needs Auto.
+AUTO2 = (jax.sharding.AxisType.Auto,) * 2
+REPO = pathlib.Path(__file__).resolve().parents[1]
+
 
 def test_a2a_equals_pjit_single_shard():
     cfg = configs.get_smoke("olmoe_1b_7b")
     p, _ = init_dense(jax.random.PRNGKey(0), moe_spec(cfg), jnp.float32)
     x = jnp.asarray(
         np.random.default_rng(1).normal(0, 1, (2, 32, cfg.d_model)), jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=AUTO2)
     rules = sh.make_rules(cfg, mesh)
     with use_mesh_rules(mesh, rules):
         y1, a1 = jax.jit(lambda p, x: moe_apply(p, x, cfg))(p, x)
@@ -37,7 +42,7 @@ def test_a2a_grads_match_single_shard():
     p, _ = init_dense(jax.random.PRNGKey(0), moe_spec(cfg), jnp.float32)
     x = jnp.asarray(
         np.random.default_rng(2).normal(0, 1, (2, 16, cfg.d_model)), jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=AUTO2)
     rules = sh.make_rules(cfg, mesh)
     with use_mesh_rules(mesh, rules):
         g1 = jax.jit(jax.grad(lambda p, x: jnp.sum(moe_apply(p, x, cfg)[0] ** 2)))(p, x)
@@ -55,16 +60,17 @@ _SUBPROCESS = textwrap.dedent("""
     from repro.models.common import init_dense
     from repro.meshctx import use_mesh_rules
     from repro.launch import sharding as sh
+    AUTO2 = (jax.sharding.AxisType.Auto,) * 2
     cfg = configs.get_smoke("olmoe_1b_7b")
     p, _ = init_dense(jax.random.PRNGKey(0), moe_spec(cfg), jnp.float32)
     x = jnp.asarray(np.random.default_rng(1).normal(0, 1, (4, 32, cfg.d_model)),
                     jnp.float32)
-    m1 = jax.make_mesh((1, 1), ("data", "model"))
+    m1 = jax.make_mesh((1, 1), ("data", "model"), axis_types=AUTO2)
     with use_mesh_rules(m1, sh.make_rules(cfg, m1)):
         y_ref, _ = jax.jit(
             lambda p, x: moe_apply(p, x, cfg, capacity_factor=8.0))(p, x)
     y_ref = np.asarray(y_ref)
-    m = jax.make_mesh((2, 4), ("data", "model"))
+    m = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO2)
     with use_mesh_rules(m, sh.make_rules(cfg, m)):
         y2, _ = jax.jit(
             lambda p, x: moe_apply_a2a(p, x, cfg, capacity_factor=8.0))(p, x)
@@ -80,7 +86,7 @@ def test_a2a_exact_on_8_devices():
         [sys.executable, "-c", _SUBPROCESS],
         capture_output=True, text=True, timeout=300,
         env={**__import__("os").environ, "PYTHONPATH": "src"},
-        cwd="/root/repo",
+        cwd=REPO,
     )
     assert r.returncode == 0, r.stderr[-2000:]
     assert "OK" in r.stdout

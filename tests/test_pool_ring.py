@@ -30,6 +30,7 @@ Acceptance contracts (ISSUE 3 + ISSUE 4 + ISSUE 5):
     join/leave churn.  ``policy="static"`` (the default) never migrates.
 """
 import dataclasses
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -41,6 +42,8 @@ import pytest
 from repro.core import pipeline
 from repro.events import synthetic
 from repro.serve import DetectorPool
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 _RING_CFG = pipeline.PipelineConfig(
     chunk=256, lut_every_chunks=2, vdd=0.6, inject_ber=True
@@ -708,7 +711,7 @@ def test_sharded_pool_4_devices_subprocess():
         [sys.executable, "-c", _SHARDED_SUBPROCESS],
         capture_output=True, text=True, timeout=600,
         env={**__import__("os").environ, "PYTHONPATH": "src"},
-        cwd="/root/repo",
+        cwd=REPO,
     )
     assert r.returncode == 0, r.stderr[-2000:]
     assert "OK" in r.stdout
