@@ -497,20 +497,21 @@ def _refresh_lut(cfg, state: DetectorState, surface, lut):
     suspends refresh outright; scoring continues against the stale LUT
     (the luvHarris overload mode: degrade quality, never latency).
     """
-    do_refresh = (
-        ((state.chunk_idx + 1) % state.ctrl.lut_every) == 0
-    ) & jnp.logical_not(state.ctrl.shed)
-    lut = jax.lax.cond(
-        do_refresh,
-        lambda s: harris_mod.harris_response(
-            s,
-            sobel_size=cfg.sobel_size,
-            window_size=cfg.window_size,
-            k=cfg.harris_k,
-        ),
-        lambda s: lut,
-        surface,
-    )
+    with jax.named_scope("lut_refresh"):
+        do_refresh = (
+            ((state.chunk_idx + 1) % state.ctrl.lut_every) == 0
+        ) & jnp.logical_not(state.ctrl.shed)
+        lut = jax.lax.cond(
+            do_refresh,
+            lambda s: harris_mod.harris_response(
+                s,
+                sobel_size=cfg.sobel_size,
+                window_size=cfg.window_size,
+                k=cfg.harris_k,
+            ),
+            lambda s: lut,
+            surface,
+        )
     return lut, do_refresh
 
 
@@ -535,30 +536,33 @@ def detector_step(
     surface, sae, lut = state.surface, state.sae, state.lut
     lut_ready, key = state.lut_ready, state.key
 
-    sae, keep = stcf_mod.stcf_step(
-        sae, chunk.xy, chunk.ts, chunk.valid,
-        enabled=cfg.stcf_enabled,
-        support=cfg.stcf_support, tw=cfg.stcf_tw_us,
-    )
+    with jax.named_scope("stcf"):
+        sae, keep = stcf_mod.stcf_step(
+            sae, chunk.xy, chunk.ts, chunk.valid,
+            enabled=cfg.stcf_enabled,
+            support=cfg.stcf_support, tw=cfg.stcf_tw_us,
+        )
 
     rate, vdd_idx, ber_c, energy_coef, latency_coef = _operating_point(
         cfg, state, chunk
     )
 
-    surface = update(surface, chunk.xy, keep)
+    with jax.named_scope("tos_update"):
+        surface = update(surface, chunk.xy, keep)
 
-    if cfg.inject_ber:
-        key, sub = jax.random.split(key)
-        surface = ber_mod.inject_write_errors_at(sub, surface, ber_c)
+        if cfg.inject_ber:
+            key, sub = jax.random.split(key)
+            surface = ber_mod.inject_write_errors_at(sub, surface, ber_c)
 
     n_kept = jnp.sum(keep).astype(jnp.int32)
 
     # Tag this chunk's events against the latest available LUT.
-    scores = jnp.where(
-        lut_ready,
-        harris_mod.score_events(lut, chunk.xy, keep),
-        -jnp.inf,
-    ).astype(jnp.float32)
+    with jax.named_scope("score_read"):
+        scores = jnp.where(
+            lut_ready,
+            harris_mod.score_events(lut, chunk.xy, keep),
+            -jnp.inf,
+        ).astype(jnp.float32)
 
     lut, do_refresh = _refresh_lut(cfg, state, surface, lut)
     lut_ready = lut_ready | do_refresh
@@ -607,19 +611,25 @@ def _detector_step_fused(
     # oracle via ber.write_error_bits), xor/decode applied in-kernel.
     bits = None
     if cfg.inject_ber:
-        key, sub = jax.random.split(key)
-        bits = ber_mod.write_error_bits(sub, surface.shape, ber_c)
+        with jax.named_scope("tos_update"):
+            key, sub = jax.random.split(key)
+            bits = ber_mod.write_error_bits(sub, surface.shape, ber_c)
 
-    surface, sae, keep, raw_scores = ops.fused_step_op(
-        surface, sae, lut, chunk.xy, chunk.ts, chunk.valid, ber_c, bits,
-        patch=cfg.patch, th=cfg.th,
-        support=cfg.stcf_support, tw=cfg.stcf_tw_us,
-        stcf_enabled=cfg.stcf_enabled, inject_ber=cfg.inject_ber,
-        interpret=cfg.interpret,
-    )
+    # STCF, the TOS update and the score read run in the one kernel: its
+    # device time is the fused_step scope's
+    with jax.named_scope("fused_step"):
+        surface, sae, keep, raw_scores = ops.fused_step_op(
+            surface, sae, lut, chunk.xy, chunk.ts, chunk.valid, ber_c, bits,
+            patch=cfg.patch, th=cfg.th,
+            support=cfg.stcf_support, tw=cfg.stcf_tw_us,
+            stcf_enabled=cfg.stcf_enabled, inject_ber=cfg.inject_ber,
+            interpret=cfg.interpret,
+        )
 
     n_kept = jnp.sum(keep).astype(jnp.int32)
-    scores = jnp.where(lut_ready, raw_scores, -jnp.inf).astype(jnp.float32)
+    with jax.named_scope("score_read"):
+        scores = jnp.where(lut_ready, raw_scores,
+                           -jnp.inf).astype(jnp.float32)
 
     lut, do_refresh = _refresh_lut(cfg, state, surface, lut)
     lut_ready = lut_ready | do_refresh

@@ -101,5 +101,6 @@ def compact_slots_call(
             jax.ShapeDtypeStruct((lp, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="compact",
     )(jnp.pad(scores, pad), jnp.pad(keep.astype(jnp.int32), pad))
     return idx[:l], val[:l], cnt[:l, 0]

@@ -242,4 +242,5 @@ def fused_chunk_step_call(
             vmem_limit_bytes=_vmem_limit(hp, wp, inject)
         ),
         interpret=interpret,
+        name="fused_step",
     )(*args)

@@ -90,7 +90,7 @@ from repro.serve import DetectorPool
 # pack summary keys, rendered by a LogSink from the SAME record the JSONL
 # trail gets — one emit, N sinks, no bespoke report block.
 _SUMMARY_FIELDS = (
-    "pump_stages", "pump_stage_s", "pump_stage_hidden_s",
+    "pump_stages", "pump_stage_s",
     "pump_stage_overlap", "ctrl_batched_writes", "ctrl_actions_coalesced",
     "observation_rebuilds", "observation_reuses", "h2d_event_slots",
     "h2d_valid_events", "migrations_total",

@@ -84,7 +84,6 @@ POOL_STATS = {
     "pump_stages_overlapped": "blocks staged while device compute ran",
     "pump_stage_overlap_ratio": "pump_stages_overlapped / pump_stages",
     "pump_stage_s": "wall seconds spent gathering/pinning/uploading",
-    "pump_stage_hidden_s": "stage seconds hidden under device compute",
     "ctrl_batched_writes": "coalesced control-leaf batch updates",
     "ctrl_actions_coalesced": "knob actions folded into those batches",
     "observation_rebuilds": "LaneObservations built fresh",
@@ -103,6 +102,18 @@ POOL_STATS = {
     "dropped_rounds_total": "rounds lost to overflow (confirmed+predicted)",
     "dropped_rounds_confirmed": "overflow drops confirmed by fetches",
     "shed_events_total": "shed events across currently-connected lanes",
+    "events_fed": "events accepted by feed, every lane (pre-shed)",
+    "feed_lock_wait_s": "wall seconds feed waited for the pool lock",
+    "chunks_returned": "chunk results handed back by poll",
+    "chunk_buffer_wait_s": "chunk-seconds from feeding a chunk's last event "
+                           "to its collect",
+    "chunk_stage_wait_s": "chunk-seconds from collect to executor launch",
+    "chunk_ring_wait_s": "chunk-seconds from launch to the ring's seal "
+                         "(sync: its drain)",
+    "chunk_fetch_wait_s": "chunk-seconds from seal until device_get returns",
+    "chunk_distribute_wait_s": "chunk-seconds from fetch to the lane's "
+                               "result queue",
+    "chunk_handoff_wait_s": "chunk-seconds from the result queue to poll",
     "buckets": "per-bucket sub-table (see bucket keys)",
 }
 
@@ -151,7 +162,13 @@ WALL_TIME_KEYS = frozenset({
     "last_drain_wait_s",
     "pump_drain_wait_s",
     "pump_stage_s",
-    "pump_stage_hidden_s",
+    "feed_lock_wait_s",
+    "chunk_buffer_wait_s",
+    "chunk_stage_wait_s",
+    "chunk_ring_wait_s",
+    "chunk_fetch_wait_s",
+    "chunk_distribute_wait_s",
+    "chunk_handoff_wait_s",
 })
 
 

@@ -343,6 +343,11 @@ class DetectorPool:
         """Lane accounting + rate/migration view; see ``PoolRuntime.stats``."""
         return self._rt.stats(lane)
 
+    def executor_hlo(self) -> list:
+        """Compiled HLO text of each executor that has run; see
+        ``PoolRuntime.executor_hlo``."""
+        return self._rt.executor_hlo()
+
     def pool_stats(self) -> dict:
         """Pool-level runtime counters plus the active policy and any
         policy-side counters (``ladder_level`` / ``ladder_transitions``

@@ -142,7 +142,8 @@ class _Lane:
                  "vdd_trace", "events_folded", "migrations", "migration_log",
                  "r_win", "r_cur", "r_p1", "r_p2",
                  "qos", "tier", "knob_lut_every", "knob_vdd_cap",
-                 "knob_shed", "shed_events", "gen", "obs_cache")
+                 "knob_shed", "shed_events", "gen", "obs_cache",
+                 "fed_cum", "feed_times", "res_n", "res_tsum")
 
     def __init__(self, bucket: int, *, qos: str = "standard",
                  lut_every: int = 1, vdd_cap: int = 0):
@@ -186,6 +187,15 @@ class _Lane:
         # pump observation, not a rebuild.
         self.gen = 0
         self.obs_cache: Optional[tuple] = None
+        # Chunk-life stamps: ``feed_times`` holds (events ever buffered
+        # after the slab, its feed time) per slab still in the buffer, so
+        # a collect finds when its chunk's last event was fed; ``res_n``
+        # results wait in ``results``, distributed at times summing to
+        # ``res_tsum``.
+        self.fed_cum = 0
+        self.feed_times: collections.deque = collections.deque()
+        self.res_n = 0
+        self.res_tsum = 0.0
 
     def rate_update(self, ts: np.ndarray, half: int) -> None:
         """Fold one time-sorted slab into the rate twin (vectorized; only
@@ -209,13 +219,16 @@ class _Lane:
 
 
 class _Round:
-    """One collected pump round (host arrays, lane-stacked) for a bucket."""
+    """One collected pump round (host arrays, lane-stacked) for a bucket,
+    with its collect time and how many chunks and events it holds."""
 
-    __slots__ = ("xy", "ts", "valid", "mask", "n_valid")
+    __slots__ = ("xy", "ts", "valid", "mask", "n_valid", "t", "n_chunks",
+                 "n_events")
 
-    def __init__(self, xy, ts, valid, mask, n_valid):
+    def __init__(self, xy, ts, valid, mask, n_valid, t, n_chunks, n_events):
         self.xy, self.ts, self.valid = xy, ts, valid
         self.mask, self.n_valid = mask, n_valid
+        self.t, self.n_chunks, self.n_events = t, n_chunks, n_events
 
 
 class _StagedBlock:
@@ -228,14 +241,17 @@ class _StagedBlock:
     therefore fences behind a pipeline flush)."""
 
     __slots__ = ("bucket", "n", "single", "chunks", "mask", "n_valid",
-                 "round_active", "n_valid_sum")
+                 "round_active", "n_valid_sum", "seq", "n_chunks", "t_sum")
 
     def __init__(self, bucket, n, single, chunks, mask, n_valid,
-                 round_active, n_valid_sum):
+                 round_active, n_valid_sum, seq, n_chunks, t_sum):
         self.bucket, self.n, self.single = bucket, n, single
         self.chunks, self.mask, self.n_valid = chunks, mask, n_valid
         self.round_active = round_active
         self.n_valid_sum = n_valid_sum
+        # the block's sequence number (its spans' ``block`` id), its
+        # chunks, and their collect times summed
+        self.seq, self.n_chunks, self.t_sum = seq, n_chunks, t_sum
 
 
 class PoolRuntime:
@@ -431,6 +447,9 @@ class PoolRuntime:
         self._exec: dict[int, object] = {}      # K-block executor
         self._exec1: dict[int, object] = {}     # 1-round fast path (K > 1)
         self._inflight: dict[int, int] = {}       # sealed rings being fetched
+        # the live ring's chunk book: [chunks, their launch times summed,
+        # block ids] — closed when the ring is sealed (or drained inline)
+        self._ring_book: dict[int, list] = {}
         for b in buckets:
             self._rings[b] = self._make_ring(b)
             self._spares[b] = collections.deque(
@@ -441,6 +460,7 @@ class PoolRuntime:
             if ring_rounds > 1:
                 self._exec1[b] = self._build_single_executor(b)
             self._inflight[b] = 0
+            self._ring_book[b] = [0, 0.0, []]
 
         # -- witnesses: every counter/gauge below lives in the metrics
         # registry (repro.obs) — the single write path.  ``stats()`` /
@@ -452,7 +472,11 @@ class PoolRuntime:
                          else obs_mod.MetricsRegistry(namespace="pool"))
         self._declare_metrics(buckets)
         self._pass_dispatches = 0  # blocks dispatched in the current pass
-        self._busy_probe = None    # an output array of the last dispatch
+        self._block_seq = 0        # blocks staged so far: the next block id
+        # (bucket, single) -> the executor as noted at its first call,
+        # from which ``executor_hlo`` lowers it again
+        self._exec_notes = obs_mod.ExecutorNotes()
+        self._t_fetched = None     # when the last fetch's device_get returned
         # One pump at a time: _seal_ring can wait on the cv (releasing the
         # lock) AFTER chunks were popped into a pending block, so a second
         # concurrent pump could otherwise collect and execute LATER chunks
@@ -536,7 +560,6 @@ class PoolRuntime:
         self._m_stages = ctr("pump_stages")
         self._m_stages_overlapped = ctr("pump_stages_overlapped")
         self._m_stage_s = ctr("pump_stage_s")
-        self._m_stage_hidden_s = ctr("pump_stage_hidden_s")
         self._m_ctrl_writes = ctr("ctrl_batched_writes")
         self._m_ctrl_coalesced = ctr("ctrl_actions_coalesced")
         self._m_obs_rebuilds = ctr("observation_rebuilds")
@@ -551,6 +574,17 @@ class PoolRuntime:
         self._m_d2h_bytes = ctr("d2h_bytes")
         self._m_d2h_saved = ctr("d2h_bytes_saved")
         self._m_d2h_overflow = ctr("d2h_compact_overflow_slots")
+        # A chunk's life in six wall-clock segments, each summed over
+        # chunks (stamps are per round, block or ring, times its chunks).
+        self._m_events_fed = ctr("events_fed")
+        self._m_feed_lock_wait = ctr("feed_lock_wait_s")
+        self._m_chunks_returned = ctr("chunks_returned")
+        self._m_chunk_buffer = ctr("chunk_buffer_wait_s")
+        self._m_chunk_stage = ctr("chunk_stage_wait_s")
+        self._m_chunk_ring = ctr("chunk_ring_wait_s")
+        self._m_chunk_fetch = ctr("chunk_fetch_wait_s")
+        self._m_chunk_distribute = ctr("chunk_distribute_wait_s")
+        self._m_chunk_handoff = ctr("chunk_handoff_wait_s")
 
         def per_bucket(metric):
             return {b: metric.labels(bucket=b) for b in buckets}
@@ -666,8 +700,10 @@ class PoolRuntime:
                     new_states, outs = jax.vmap(
                         lambda s, c: state_mod.detector_step(tcfg, s, c)
                     )(states, chunk)
-                    states = _mask_tree(m, new_states, states)
-                    ring = push(ring, outs, m, nv, act)
+                    with jax.named_scope("mask_select"):
+                        states = _mask_tree(m, new_states, states)
+                    with jax.named_scope("ring_push"):
+                        ring = push(ring, outs, m, nv, act)
                     return states, ring
 
                 states, ring = jax.lax.cond(
@@ -716,8 +752,10 @@ class PoolRuntime:
             new_states, outs = jax.vmap(
                 lambda s, c: state_mod.detector_step(tcfg, s, c)
             )(states, chunk)
-            states = _mask_tree(mask, new_states, states)
-            ring = push(ring, outs, mask, n_valid, jnp.bool_(True))
+            with jax.named_scope("mask_select"):
+                states = _mask_tree(mask, new_states, states)
+            with jax.named_scope("ring_push"):
+                ring = push(ring, outs, mask, n_valid, jnp.bool_(True))
             return states, ring
 
         if self._mesh is not None:
@@ -771,7 +809,7 @@ class PoolRuntime:
         if self._cfg.backend == "jnp":
             from repro.kernels import ref as ref_mod  # pure jnp, Pallas-free
 
-            compact_fn = jax.vmap(
+            compact_lanes = jax.vmap(
                 lambda s, k: ref_mod.compact_ref(s, k, cap=cap)
             )
         else:
@@ -779,10 +817,14 @@ class PoolRuntime:
 
             interpret = self._cfg.interpret
 
-            def compact_fn(s, k):
+            def compact_lanes(s, k):
                 return ops.compact_slots_op(
                     s, k, cap=cap, interpret=interpret
                 )
+
+        def compact_fn(s, k):
+            with jax.named_scope("compact"):
+                return compact_lanes(s, k)
 
         import functools
 
@@ -921,6 +963,14 @@ class PoolRuntime:
             out[b] = d
         return out
 
+    def executor_hlo(self) -> list:
+        """The compiled HLO text of every executor that has run, lowered
+        again from the abstract arguments of its first call (the same
+        program, so the same instruction names as in a device trace;
+        nothing new is compiled where JAX still holds the executable).
+        ``repro.obs.latest_hlo_texts`` gives the same after ``close``."""
+        return self._exec_notes.hlo_texts()
+
     def executors_compiled_once(self) -> bool:
         """The churn witness: every executor (per bucket, per block shape)
         has compiled at most one executable."""
@@ -936,25 +986,35 @@ class PoolRuntime:
         rounds, dropping the *oldest* buffered events (the real-time
         regime: stale events are worthless; the rate twin still counts
         them, so recovery sees the true arrival rate)."""
-        with self._lock:
-            self._check_open()
-            self._check_lane(lane)
-            ln = self._lanes[lane]
-            xy = np.asarray(xy, np.int32).reshape(-1, 2)
-            ts = np.asarray(ts_us, np.int64).reshape(-1)
-            if not ts.size:
-                return
-            if ln.base is None:
-                ln.base = streaming_mod.session_base_us(
-                    int(ts[0]), self._cfg
-                )
-            ln.buf_xy = np.concatenate([ln.buf_xy, xy], 0)
-            ln.buf_ts = np.concatenate([ln.buf_ts, ts], 0)
-            ln.n_events += int(ts.size)
-            ln.rate_update(ts, self._half_us)
-            ln.gen += 1           # backlog and rate twin changed
-            if ln.knob_shed:
-                self._shed_buffer(ln)
+        with obs_mod.span("feed"):
+            t0 = obs_mod.timer()
+            self._take_lock()
+            try:
+                t_fed = obs_mod.timer()
+                self._m_feed_lock_wait.inc(t_fed - t0)
+                self._check_open()
+                self._check_lane(lane)
+                ln = self._lanes[lane]
+                xy = np.asarray(xy, np.int32).reshape(-1, 2)
+                ts = np.asarray(ts_us, np.int64).reshape(-1)
+                if not ts.size:
+                    return
+                if ln.base is None:
+                    ln.base = streaming_mod.session_base_us(
+                        int(ts[0]), self._cfg
+                    )
+                ln.buf_xy = np.concatenate([ln.buf_xy, xy], 0)
+                ln.buf_ts = np.concatenate([ln.buf_ts, ts], 0)
+                ln.n_events += int(ts.size)
+                ln.fed_cum += int(ts.size)
+                ln.feed_times.append((ln.fed_cum, t_fed))
+                self._m_events_fed.inc(int(ts.size))
+                ln.rate_update(ts, self._half_us)
+                ln.gen += 1           # backlog and rate twin changed
+                if ln.knob_shed:
+                    self._shed_buffer(ln)
+            finally:
+                self._lock.release()
 
     def _shed_buffer(self, ln: _Lane) -> None:
         """Drop-oldest a shedding lane's re-chunk buffer down to one ring
@@ -966,6 +1026,9 @@ class PoolRuntime:
             ln.buf_ts = ln.buf_ts[excess:]
             ln.shed_events += excess
             ln.gen += 1           # backlog changed
+            head = ln.fed_cum - int(ln.buf_ts.size)
+            while ln.feed_times and ln.feed_times[0][0] <= head:
+                ln.feed_times.popleft()     # slabs shed whole
 
     def pump_pass(self, order: tuple,
                   max_rounds: Optional[int] = None,
@@ -996,15 +1059,16 @@ class PoolRuntime:
         returns (``finally`` — an exception mid-pass cannot strand an
         uploaded block), so every staged round executes exactly once, in
         the serial path's order."""
-        with self._lock:
+        with obs_mod.span("pump_pass"), self._lock:
             self._check_open()
             self._acquire_pump()
             try:
-                self._apply_staged_locked()
-                if decide is not None:
-                    actions = decide(self._observation_locked())
-                    if actions:
-                        self._apply_actions_locked(actions)
+                with obs_mod.span("control"):
+                    self._apply_staged_locked()
+                    if decide is not None:
+                        actions = decide(self._observation_locked())
+                        if actions:
+                            self._apply_actions_locked(actions)
                 total = 0
                 q: collections.deque = collections.deque()
                 self._pass_dispatches = 0
@@ -1049,6 +1113,13 @@ class PoolRuntime:
                 self._release_pump()
             return self.poll(lane)
 
+    def _take_lock(self) -> None:
+        """Acquire the pool lock; a wait for it is a ``pool.lock_wait``
+        span (an uncontended take records none)."""
+        if not self._lock.acquire(blocking=False):
+            with obs_mod.span("lock_wait"):
+                self._lock.acquire()
+
     def _acquire_pump(self) -> None:
         """Take the pump token (caller holds the lock); waits out any pump
         in flight so two pumpers cannot interleave their round order."""
@@ -1080,25 +1151,50 @@ class PoolRuntime:
         a later poll.  Under ``on_overflow="drop_oldest"``, rounds lost to
         overflow are simply absent here and counted in
         ``stats()['ring_dropped_rounds']``."""
-        with self._lock:
-            self._check_open()
-            self._check_lane(lane)
-            bucket = self._lanes[lane].bucket
-            self._drain_bucket(bucket, wait=wait, block=wait)
-            # re-validate: an async drain waits on the reader with the
-            # lock released, so a concurrent disconnect may have retired
-            # the lane — surface the documented KeyError, not a crash on
-            # the None slot
-            self._check_lane(lane)
-            ln = self._lanes[lane]
-            if not ln.results:
-                return (np.zeros((0,), np.float32), np.zeros((0,), bool))
-            scores = np.concatenate(
-                [r[0] for r in ln.results]
-            ).astype(np.float32)
-            kept = np.concatenate([r[1] for r in ln.results]).astype(bool)
-            ln.results.clear()
-            return scores, kept
+        self._take_lock()
+        try:
+            if not wait and not self._poll_finds_work(lane):
+                return self._poll_locked(lane, wait)    # no span: a no-op
+            with obs_mod.span("poll"):
+                return self._poll_locked(lane, wait)
+        finally:
+            self._lock.release()
+
+    def _poll_finds_work(self, lane: int) -> bool:
+        """Whether a non-blocking poll of ``lane`` hands back results or
+        seals a ring (caller holds the lock): a poller spinning over idle
+        lanes records no span."""
+        ln = self._lanes[lane] if 0 <= lane < self._capacity else None
+        if ln is None:
+            return True
+        b = ln.bucket
+        return bool(ln.results) or (
+            self._drain_mode == "async" and bool(self._spares[b])
+            and self._m_ring_count[b].value() > 0)
+
+    def _poll_locked(self, lane: int, wait: bool):
+        """The body of ``poll`` (caller holds the lock); hands the lane's
+        results back and counts their handoff wait."""
+        self._check_open()
+        self._check_lane(lane)
+        bucket = self._lanes[lane].bucket
+        self._drain_bucket(bucket, wait=wait, block=wait)
+        # re-validate: an async drain waits on the reader with the lock
+        # released, so a concurrent disconnect may have retired the lane —
+        # surface the documented KeyError, not a crash on the None slot
+        self._check_lane(lane)
+        ln = self._lanes[lane]
+        if not ln.results:
+            return (np.zeros((0,), np.float32), np.zeros((0,), bool))
+        scores = np.concatenate(
+            [r[0] for r in ln.results]
+        ).astype(np.float32)
+        kept = np.concatenate([r[1] for r in ln.results]).astype(bool)
+        ln.results.clear()
+        self._m_chunk_handoff.inc(ln.res_n * obs_mod.timer() - ln.res_tsum)
+        self._m_chunks_returned.inc(ln.res_n)
+        ln.res_n, ln.res_tsum = 0, 0.0
+        return scores, kept
 
     # -- migration mechanics -------------------------------------------------
 
@@ -1580,15 +1676,13 @@ class PoolRuntime:
                 # pipelined-pump witnesses: how many block stages began
                 # while an earlier block of the same pass was already
                 # dispatched (structural, deterministic at fixed sizes),
-                # plus the wall time staging took and how much of it ran
-                # while the device still reported the last dispatch busy
+                # plus the wall time staging took
                 "pump_stages": stages,
                 "pump_stages_overlapped": overlapped,
                 "pump_stage_overlap_ratio": (
                     overlapped / stages if stages else 0.0
                 ),
                 "pump_stage_s": float(self._m_stage_s.value()),
-                "pump_stage_hidden_s": float(self._m_stage_hidden_s.value()),
                 "ctrl_batched_writes": self._m_ctrl_writes.value(),
                 "ctrl_actions_coalesced": self._m_ctrl_coalesced.value(),
                 "observation_rebuilds": self._m_obs_rebuilds.value(),
@@ -1617,6 +1711,18 @@ class PoolRuntime:
                 "shed_events_total": sum(
                     ln.shed_events for ln in self._lanes if ln is not None
                 ),
+                # a chunk's life: ingest, the feed's lock wait, and the
+                # six segments from feed to poll, each summed over chunks
+                "events_fed": self._m_events_fed.value(),
+                "feed_lock_wait_s": float(self._m_feed_lock_wait.value()),
+                "chunks_returned": self._m_chunks_returned.value(),
+                "chunk_buffer_wait_s": float(self._m_chunk_buffer.value()),
+                "chunk_stage_wait_s": float(self._m_chunk_stage.value()),
+                "chunk_ring_wait_s": float(self._m_chunk_ring.value()),
+                "chunk_fetch_wait_s": float(self._m_chunk_fetch.value()),
+                "chunk_distribute_wait_s": float(
+                    self._m_chunk_distribute.value()),
+                "chunk_handoff_wait_s": float(self._m_chunk_handoff.value()),
                 "buckets": {
                     b: {
                         "lanes": sum(
@@ -1681,26 +1787,28 @@ class PoolRuntime:
         while True:
             pending: list[_Round] = []
             stop = False
-            while len(pending) < self._ring_rounds:
-                if max_rounds is not None and \
-                        executed + len(pending) >= max_rounds:
-                    stop = True
-                    break
-                rnd = self._collect_round(
-                    bucket, flush_lane,
-                    allow_rebase=not pending and not q,
-                )
-                if rnd == "rebase":
-                    if not pending and q:
-                        # blocked only by staged-ahead blocks: drain the
-                        # pipeline, then retry with the rebase allowed
-                        self._flush_pipeline(q)
-                        continue
-                    break          # cut the block; rebase opens the next one
-                if rnd is None:
-                    stop = True
-                    break
-                pending.append(rnd)
+            with obs_mod.span("collect"):
+                while len(pending) < self._ring_rounds:
+                    if max_rounds is not None and \
+                            executed + len(pending) >= max_rounds:
+                        stop = True
+                        break
+                    rnd = self._collect_round(
+                        bucket, flush_lane,
+                        allow_rebase=not pending and not q,
+                    )
+                    if rnd == "rebase":
+                        if not pending and q:
+                            # blocked only by staged-ahead blocks: drain
+                            # the pipeline, then retry with the rebase
+                            # allowed
+                            self._flush_pipeline(q)
+                            continue
+                        break      # cut the block; rebase opens the next
+                    if rnd is None:
+                        stop = True
+                        break
+                    pending.append(rnd)
             if pending:
                 q.append(self._stage_block(bucket, pending,
                                            stage_ahead=bool(q)))
@@ -1761,8 +1869,18 @@ class PoolRuntime:
         valid = np.zeros((self._phys, bucket), bool)
         mask = np.zeros((self._phys,), bool)
         n_valid = np.zeros((self._phys,), np.int32)
+        t = obs_mod.timer()
+        fed_sum = 0.0          # feed times of the chunks' last events
+        n_events = 0
         for lane, n in ready:
             ln = self._lanes[lane]
+            last = ln.fed_cum - int(ln.buf_ts.size) + n
+            while ln.feed_times[0][0] < last:
+                ln.feed_times.popleft()
+            fed_sum += ln.feed_times[0][1]
+            if ln.feed_times[0][0] == last:
+                ln.feed_times.popleft()
+            n_events += n
             xy[lane, :n] = ln.buf_xy[:n]
             ts64 = np.full((bucket,), ln.buf_ts[min(n, ln.buf_ts.size) - 1],
                            np.int64)
@@ -1775,7 +1893,8 @@ class PoolRuntime:
             ln.buf_ts = ln.buf_ts[n:]
             ln.events_folded += n
             ln.gen += 1           # backlog changed
-        return _Round(xy, ts, valid, mask, n_valid)
+        self._m_chunk_buffer.inc(len(ready) * t - fed_sum)
+        return _Round(xy, ts, valid, mask, n_valid, t, len(ready), n_events)
 
     def _stage_block(self, bucket: int, rounds: list, *,
                      stage_ahead: bool = False) -> _StagedBlock:
@@ -1792,60 +1911,70 @@ class PoolRuntime:
         """
         k = self._ring_rounds
         n = len(rounds)
-        t0 = obs_mod.timer()
-        up = self._stager.put if self._stager is not None else jnp.asarray
-        if n == 1 and bucket in self._exec1:
-            rnd = rounds[0]
-            chunks = state_mod.ChunkInput(
-                xy=up(rnd.xy),
-                ts=up(rnd.ts),
-                valid=up(rnd.valid),
-                ber=jnp.full((self._phys,), self._riders[0], jnp.float32),
-                energy_coef=jnp.full(
-                    (self._phys,), self._riders[1], jnp.float32
-                ),
-                latency_coef=jnp.full(
-                    (self._phys,), self._riders[2], jnp.float32
-                ),
-            )
-            blk = _StagedBlock(
-                bucket, n, True, chunks, up(rnd.mask), up(rnd.n_valid),
-                None, int(rnd.n_valid.sum()),
-            )
-            self._m_h2d_slots[bucket].inc(self._phys * bucket)
-        else:
-            xy = np.zeros((k, self._phys, bucket, 2), np.int32)
-            ts = np.zeros((k, self._phys, bucket), np.int32)
-            valid = np.zeros((k, self._phys, bucket), bool)
-            mask = np.zeros((k, self._phys), bool)
-            n_valid = np.zeros((k, self._phys), np.int32)
-            for i, rnd in enumerate(rounds):
-                xy[i], ts[i], valid[i] = rnd.xy, rnd.ts, rnd.valid
-                mask[i], n_valid[i] = rnd.mask, rnd.n_valid
-            round_active = np.arange(k) < n
+        seq = self._block_seq
+        self._block_seq += 1
+        book = (sum(r.n_events for r in rounds), seq,
+                sum(r.n_chunks for r in rounds),
+                sum(r.n_chunks * r.t for r in rounds))
+        with obs_mod.span("stage", block=seq, bucket=bucket, rounds=n,
+                          valid_events=book[0]):
+            t0 = obs_mod.timer()
+            up = (self._stager.put if self._stager is not None
+                  else jnp.asarray)
+            if n == 1 and bucket in self._exec1:
+                rnd = rounds[0]
+                chunks = state_mod.ChunkInput(
+                    xy=up(rnd.xy),
+                    ts=up(rnd.ts),
+                    valid=up(rnd.valid),
+                    ber=jnp.full(
+                        (self._phys,), self._riders[0], jnp.float32
+                    ),
+                    energy_coef=jnp.full(
+                        (self._phys,), self._riders[1], jnp.float32
+                    ),
+                    latency_coef=jnp.full(
+                        (self._phys,), self._riders[2], jnp.float32
+                    ),
+                )
+                blk = _StagedBlock(
+                    bucket, n, True, chunks, up(rnd.mask), up(rnd.n_valid),
+                    None, *book,
+                )
+                self._m_h2d_slots[bucket].inc(self._phys * bucket)
+            else:
+                xy = np.zeros((k, self._phys, bucket, 2), np.int32)
+                ts = np.zeros((k, self._phys, bucket), np.int32)
+                valid = np.zeros((k, self._phys, bucket), bool)
+                mask = np.zeros((k, self._phys), bool)
+                n_valid = np.zeros((k, self._phys), np.int32)
+                for i, rnd in enumerate(rounds):
+                    xy[i], ts[i], valid[i] = rnd.xy, rnd.ts, rnd.valid
+                    mask[i], n_valid[i] = rnd.mask, rnd.n_valid
+                round_active = np.arange(k) < n
 
-            chunks = state_mod.ChunkInput(
-                xy=up(xy),
-                ts=up(ts),
-                valid=up(valid),
-                ber=jnp.full((k, self._phys), self._riders[0], jnp.float32),
-                energy_coef=jnp.full(
-                    (k, self._phys), self._riders[1], jnp.float32
-                ),
-                latency_coef=jnp.full(
-                    (k, self._phys), self._riders[2], jnp.float32
-                ),
-            )
-            blk = _StagedBlock(
-                bucket, n, False, chunks, jnp.asarray(mask),
-                jnp.asarray(n_valid), jnp.asarray(round_active),
-                int(n_valid.sum()),
-            )
-            self._m_h2d_slots[bucket].inc(k * self._phys * bucket)
-        self._m_h2d_valid[bucket].inc(blk.n_valid_sum)
-        dt = obs_mod.timer() - t0
-        self._m_stages.inc()
-        self._m_stage_s.inc(dt)
+                chunks = state_mod.ChunkInput(
+                    xy=up(xy),
+                    ts=up(ts),
+                    valid=up(valid),
+                    ber=jnp.full(
+                        (k, self._phys), self._riders[0], jnp.float32
+                    ),
+                    energy_coef=jnp.full(
+                        (k, self._phys), self._riders[1], jnp.float32
+                    ),
+                    latency_coef=jnp.full(
+                        (k, self._phys), self._riders[2], jnp.float32
+                    ),
+                )
+                blk = _StagedBlock(
+                    bucket, n, False, chunks, jnp.asarray(mask),
+                    jnp.asarray(n_valid), jnp.asarray(round_active), *book,
+                )
+                self._m_h2d_slots[bucket].inc(k * self._phys * bucket)
+            self._m_h2d_valid[bucket].inc(blk.n_valid_sum)
+            self._m_stages.inc()
+            self._m_stage_s.inc(obs_mod.timer() - t0)
         if stage_ahead and self._pass_dispatches > 0:
             # structural overlap witness: this stage began with an earlier
             # block staged-but-undispatched in the deque AND a block of
@@ -1854,9 +1983,6 @@ class PoolRuntime:
             # depth 1 the deque is always empty here, so the serial pump
             # reports 0 by construction.
             self._m_stages_overlapped.inc()
-            if self._busy_probe is not None and \
-                    not self._busy_probe.is_ready():
-                self._m_stage_hidden_s.inc(dt)
         return blk
 
     def _dispatch_block(self, blk: _StagedBlock) -> None:
@@ -1865,34 +1991,35 @@ class PoolRuntime:
         inline fetch; async: seal to the reader and keep pumping, the
         wait, if any, is for a spare ring, not for PCIe) and launch the
         staged block's executor."""
-        bucket, k, n = blk.bucket, self._ring_rounds, blk.n
-        if self._overflow == "drain" and \
-                self._m_ring_count[bucket].value() + n > k:
-            t0 = obs_mod.timer()
-            self._drain_bucket(bucket, wait=False)
-            w = obs_mod.timer() - t0
-            self._m_drain_wait.inc(w)
-            self._m_last_drain_wait[bucket].set(w)
-            self._m_forced_drains.inc()
+        with obs_mod.span("dispatch", block=blk.seq):
+            bucket, k, n = blk.bucket, self._ring_rounds, blk.n
+            if self._overflow == "drain" and \
+                    self._m_ring_count[bucket].value() + n > k:
+                t0 = obs_mod.timer()
+                self._drain_bucket(bucket, wait=False)
+                w = obs_mod.timer() - t0
+                self._m_drain_wait.inc(w)
+                self._m_last_drain_wait[bucket].set(w)
+                self._m_forced_drains.inc()
 
-        if blk.single:
-            self._states, self._rings[bucket] = self._exec1[bucket](
-                self._states, self._rings[bucket], blk.chunks,
-                blk.mask, blk.n_valid,
-            )
-        else:
-            self._states, self._rings[bucket] = self._exec[bucket](
-                self._states, self._rings[bucket], blk.chunks,
-                blk.mask, blk.n_valid, blk.round_active,
-            )
-        c = self._m_ring_count[bucket].value()
-        self._m_ring_count[bucket].set(min(c + n, k))
-        self._m_dropped_pred[bucket].add(max(0, c + n - k))
-        self._m_rounds_executed.inc(n)
-        self._pass_dispatches += 1
-        # any output array works as the device-busy probe for the next
-        # stage's hidden-time accounting (is_ready() never blocks)
-        self._busy_probe = self._rings[bucket].n_kept
+            t = obs_mod.timer()
+            self._m_chunk_stage.inc(blk.n_chunks * t - blk.t_sum)
+            book = self._ring_book[bucket]
+            book[0] += blk.n_chunks
+            book[1] += blk.n_chunks * t
+            book[2].append(blk.seq)
+            args = (self._states, self._rings[bucket], blk.chunks, blk.mask,
+                    blk.n_valid)
+            if not blk.single:
+                args += (blk.round_active,)
+            run = self._exec1[bucket] if blk.single else self._exec[bucket]
+            self._exec_notes.note((bucket, blk.single), run, args)
+            self._states, self._rings[bucket] = run(*args)
+            c = self._m_ring_count[bucket].value()
+            self._m_ring_count[bucket].set(min(c + n, k))
+            self._m_dropped_pred[bucket].add(max(0, c + n - k))
+            self._m_rounds_executed.inc(n)
+            self._pass_dispatches += 1
 
     # -- draining: sync (inline fetch) and async (seal to the reader) -------
 
@@ -1918,11 +2045,32 @@ class PoolRuntime:
         thread, then distribute and mark the ring empty."""
         if self._m_ring_count[bucket].value() == 0:
             return
-        ring = self._fetch_ring(self._rings[bucket])
+        t_seal, blocks = self._close_ring_book(bucket)
+        ring, t_fetched = self._timed_fetch(self._rings[bucket], blocks)
         self._m_host_fetches.inc()
-        self._distribute(bucket, ring)
+        with obs_mod.span("distribute"):
+            self._distribute(bucket, ring, t_seal, t_fetched)
         self._m_ring_count[bucket].set(0)
         self._rings[bucket] = self._reset_ring(self._rings[bucket])
+
+    def _close_ring_book(self, bucket: int) -> tuple:
+        """The live ring leaves the pump (sealed, or drained inline): count
+        its chunks' ring wait and start a fresh book.  Returns the seal
+        time and the ring's block ids."""
+        n, t_sum, blocks = self._ring_book[bucket]
+        t = obs_mod.timer()
+        self._m_chunk_ring.inc(n * t - t_sum)
+        self._ring_book[bucket] = [0, 0.0, []]
+        return t, blocks
+
+    def _timed_fetch(self, ring, blocks: list) -> tuple:
+        """``_fetch_ring`` in its ``pool.fetch`` span; returns the host
+        ring and when its ``device_get`` returned."""
+        self._t_fetched = None
+        with obs_mod.span("fetch", blocks=",".join(map(str, blocks))):
+            host = self._fetch_ring(ring)
+        t = self._t_fetched
+        return host, obs_mod.timer() if t is None else t
 
     def _seal_ring(self, bucket: int, *, block: bool = True) -> None:
         """Async mode's atomic swap point (caller holds the lock): install
@@ -1934,23 +2082,27 @@ class PoolRuntime:
         (the live ring keeps accumulating; a later poll seals it)."""
         if self._m_ring_count[bucket].value() == 0:
             return
-        while not self._spares[bucket]:
+        if not self._spares[bucket]:
             if not block:
                 return
-            self._check_open()
-            self._cv.wait()
-            # re-validate after the wakeup: another thread (a concurrent
-            # poll, or the pump making room) may have sealed meanwhile —
-            # sealing an empty ring would cost a pointless blocking fetch
-            # and inflate the rounds-per-fetch witness
-            if self._m_ring_count[bucket].value() == 0:
-                return
+            with obs_mod.span("seal_wait"):
+                while not self._spares[bucket]:
+                    self._check_open()
+                    self._cv.wait()
+                    # re-validate after the wakeup: another thread (a
+                    # concurrent poll, or the pump making room) may have
+                    # sealed meanwhile — sealing an empty ring would cost a
+                    # pointless blocking fetch and inflate the
+                    # rounds-per-fetch witness
+                    if self._m_ring_count[bucket].value() == 0:
+                        return
         sealed = self._rings[bucket]
         self._rings[bucket] = self._spares[bucket].popleft()
         self._m_sealed[bucket].add(self._m_ring_count[bucket].value())
         self._inflight[bucket] += 1
         self._m_ring_count[bucket].set(0)
-        self._sealed_q.put((bucket, sealed))
+        t_seal, blocks = self._close_ring_book(bucket)
+        self._sealed_q.put((bucket, sealed, t_seal, blocks))
 
     def _wait_bucket_drained(self, bucket: int) -> None:
         """Block (releasing the lock) until the reader has fetched and
@@ -1970,6 +2122,7 @@ class PoolRuntime:
         if self._readout == "compact":
             return self._fetch_compact(ring)
         host = jax.device_get(ring)
+        self._t_fetched = obs_mod.timer()
         self._m_d2h_bytes.inc(obs_mod.leaves_nbytes(*host))
         return host
 
@@ -1994,53 +2147,58 @@ class PoolRuntime:
             leaves.append(ring.vdd_idx)
         (c_idx, c_val, n_kept, n_valid, mask,
          head, count, dropped, *rest) = jax.device_get(leaves)
-        vdd_idx = rest[0] if rest else np.zeros((rounds, lanes), np.int32)
-        fetched = obs_mod.leaves_nbytes(*leaves)
+        self._t_fetched = obs_mod.timer()
+        with obs_mod.span("densify"):
+            vdd_idx = (rest[0] if rest
+                       else np.zeros((rounds, lanes), np.int32))
+            fetched = obs_mod.leaves_nbytes(*leaves)
 
-        # Overflowed slot-lanes fall back to their dense rows.  Restrict
-        # the scan to undrained slots: recycled rings only reset their
-        # cursors, so stale (already-drained) slots can still look masked.
-        live = state_mod.ring_slot_order(int(head), int(count), rounds)
-        rows = [
-            (slot, int(lane))
-            for slot in live
-            for lane in np.flatnonzero(mask[slot] & (n_kept[slot] > cap))
-        ]
-        over = []
-        if rows:
-            over = jax.device_get(
-                [(ring.scores[s, l], ring.keep[s, l]) for s, l in rows]
+            # Overflowed slot-lanes fall back to their dense rows.
+            # Restrict the scan to undrained slots: recycled rings only
+            # reset their cursors, so stale (already-drained) slots can
+            # still look masked.
+            live = state_mod.ring_slot_order(int(head), int(count), rounds)
+            rows = [
+                (slot, int(lane))
+                for slot in live
+                for lane in np.flatnonzero(
+                    mask[slot] & (n_kept[slot] > cap))
+            ]
+            over = []
+            if rows:
+                over = jax.device_get(
+                    [(ring.scores[s, l], ring.keep[s, l]) for s, l in rows]
+                )
+                fetched += obs_mod.leaves_nbytes(*over)
+                self._m_d2h_overflow.inc(len(rows))
+
+            scores = np.full((rounds, lanes, chunk), -np.inf, np.float32)
+            keep = np.zeros((rounds, lanes, chunk), bool)
+            for slot in live:
+                for lane in np.flatnonzero(mask[slot]):
+                    nk = int(n_kept[slot, lane])
+                    if nk > cap:
+                        continue  # filled from the overflow gather below
+                    idx = c_idx[slot, lane, :nk]
+                    scores[slot, lane, idx] = c_val[slot, lane, :nk]
+                    keep[slot, lane, idx] = True
+            for (slot, lane), (s_row, k_row) in zip(rows, over):
+                scores[slot, lane] = np.asarray(s_row, np.float32)
+                keep[slot, lane] = np.asarray(k_row, bool)
+
+            self._m_d2h_bytes.inc(fetched)
+            # nbytes is metadata on device arrays — the dense-equivalent
+            # baseline costs no transfer and no sync.
+            dense_eq = obs_mod.leaves_nbytes(
+                ring.scores, ring.keep, ring.n_kept, ring.vdd_idx,
+                ring.n_valid, ring.mask, ring.head, ring.count, ring.dropped,
             )
-            fetched += obs_mod.leaves_nbytes(*over)
-            self._m_d2h_overflow.inc(len(rows))
-
-        scores = np.full((rounds, lanes, chunk), -np.inf, np.float32)
-        keep = np.zeros((rounds, lanes, chunk), bool)
-        for slot in live:
-            for lane in np.flatnonzero(mask[slot]):
-                nk = int(n_kept[slot, lane])
-                if nk > cap:
-                    continue  # filled from the overflow gather below
-                idx = c_idx[slot, lane, :nk]
-                scores[slot, lane, idx] = c_val[slot, lane, :nk]
-                keep[slot, lane, idx] = True
-        for (slot, lane), (s_row, k_row) in zip(rows, over):
-            scores[slot, lane] = np.asarray(s_row, np.float32)
-            keep[slot, lane] = np.asarray(k_row, bool)
-
-        self._m_d2h_bytes.inc(fetched)
-        # nbytes is metadata on device arrays — the dense-equivalent
-        # baseline costs no transfer and no sync.
-        dense_eq = obs_mod.leaves_nbytes(
-            ring.scores, ring.keep, ring.n_kept, ring.vdd_idx,
-            ring.n_valid, ring.mask, ring.head, ring.count, ring.dropped,
-        )
-        self._m_d2h_saved.inc(max(0, dense_eq - fetched))
-        return state_mod.RingState(
-            scores=scores, keep=keep, n_kept=n_kept, vdd_idx=vdd_idx,
-            n_valid=n_valid, mask=mask, head=head, count=count,
-            dropped=dropped,
-        )
+            self._m_d2h_saved.inc(max(0, dense_eq - fetched))
+            return state_mod.RingState(
+                scores=scores, keep=keep, n_kept=n_kept, vdd_idx=vdd_idx,
+                n_valid=n_valid, mask=mask, head=head, count=count,
+                dropped=dropped,
+            )
 
     def _reader_loop(self) -> None:
         """Async drain: fetch sealed rings FIFO (order preserves the
@@ -2051,18 +2209,19 @@ class PoolRuntime:
             item = self._sealed_q.get()
             if item is _STOP:
                 return
-            bucket, sealed = item
+            bucket, sealed, t_seal, blocks = item
             try:
-                host = self._fetch_ring(sealed)
+                host, t_fetched = self._timed_fetch(sealed, blocks)
             except BaseException as e:
                 with self._cv:
                     self._reader_exc = e
                     self._cv.notify_all()
                 return
-            with self._cv:
+            with obs_mod.span("distribute"):
+                self._take_lock()
                 try:
                     self._m_host_fetches.inc()
-                    self._distribute(bucket, host)
+                    self._distribute(bucket, host, t_seal, t_fetched)
                     self._spares[bucket].append(self._reset_ring(sealed))
                     self._m_sealed[bucket].set(max(
                         0, self._m_sealed[bucket].value() - int(host.count)
@@ -2072,19 +2231,26 @@ class PoolRuntime:
                     self._reader_exc = e
                     self._cv.notify_all()
                     return
-                self._cv.notify_all()
+                else:
+                    self._cv.notify_all()
+                finally:
+                    self._lock.release()
 
-    def _distribute(self, bucket: int, ring) -> None:
+    def _distribute(self, bucket: int, ring, t_seal: float,
+                    t_fetched: float) -> None:
         """Walk a fetched ring's undrained slots (oldest first), hand each
         lane its results, fold the float64 accounting, and audit the drop
         mirror against the device counter (caller holds the lock; ``ring``
-        is host data)."""
+        is host data).  The ring was sealed at ``t_seal`` and its
+        ``device_get`` returned at ``t_fetched``."""
         n_slots = ring.scores.shape[0]
+        got: dict = {}            # lane -> chunks handed to it
         for slot in state_mod.ring_slot_order(ring.head, ring.count, n_slots):
             for lane in np.flatnonzero(ring.mask[slot]):
                 ln = self._lanes[int(lane)]
                 if ln is None:
                     continue
+                got[ln] = got.get(ln, 0) + 1
                 n = int(ring.n_valid[slot, lane])
                 streaming_mod.account_chunk(
                     ln, ring.n_kept[slot, lane], ring.vdd_idx[slot, lane],
@@ -2098,6 +2264,13 @@ class PoolRuntime:
                                                        copy=True),
                     ring.keep[slot, lane, :n].astype(bool, copy=True),
                 ))
+        t = obs_mod.timer()
+        n = sum(got.values())
+        self._m_chunk_fetch.inc(n * (t_fetched - t_seal))
+        self._m_chunk_distribute.inc(n * (t - t_fetched))
+        for ln, c in got.items():
+            ln.res_n += c
+            ln.res_tsum += c * t
         # The device counter is ground truth: drops confirmed by this fetch
         # move from the predicted mirror to the confirmed tally.  (Each ring
         # resets its dropped counter when recycled, so per-fetch counts are

@@ -227,3 +227,34 @@ def test_emit_record_is_json_serializable():
     json.dumps(rec)
     assert rec["metrics"]["hits"] == 1
     assert rec["scheduler"]["policy"] == "static"
+
+
+# -- executor notes -----------------------------------------------------------
+
+def test_executor_notes_outlive_their_owner():
+    """A runtime notes each executor once, at its first call; the newest
+    notes give the compiled HLO after the owner is gone."""
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: x * 2)
+    g = jax.jit(lambda x, y: x + y)
+    x = jnp.ones(4)
+    notes = obs.ExecutorNotes()
+    notes.note(("b", True), g, (x, x))
+    notes.note(("a", False), f, (x,))
+    notes.note(("a", False), f, (jnp.ones(8),))      # noted already
+    texts = notes.hlo_texts()
+    assert [t.split(",")[0] for t in texts] == ["HloModule jit__lambda"] * 2
+    assert "f32[4]" in texts[0] and "multiply" in texts[0]
+    assert "add" in texts[1]
+    assert obs.latest_hlo_texts() == texts
+    del notes
+    gc.collect()
+    assert obs.latest_hlo_texts() == texts
+    other = obs.ExecutorNotes()
+    assert obs.latest_hlo_texts() == texts          # nothing noted yet
+    other.note("c", f, (jnp.ones(2),))
+    assert len(obs.latest_hlo_texts()) == 1

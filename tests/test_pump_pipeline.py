@@ -166,7 +166,6 @@ def test_overlap_counters_structural():
 
     s1, k1, ps1 = burst(1)
     assert ps1["pump_stages_overlapped"] == 0
-    assert ps1["pump_stage_hidden_s"] == 0.0
     np.testing.assert_array_equal(s1, s2)
     np.testing.assert_array_equal(k1, k2)
 
