@@ -76,6 +76,9 @@ __all__ = [
     "detector_step",
     "detector_scan",
     "donation_ok",
+    "lut_due",
+    "refresh_ladder",
+    "refresh_luts",
     "rate_estimate_eps",
     "ring_init",
     "ring_push",
@@ -489,30 +492,101 @@ def _operating_point(cfg, state: DetectorState, chunk: ChunkInput):
             chunk.energy_coef, chunk.latency_coef)
 
 
-def _refresh_lut(cfg, state: DetectorState, surface, lut):
-    """Periodic Harris LUT rebuild; returns (lut, do_refresh).
+def lut_due(state: DetectorState) -> jax.Array:
+    """Whether folding the next chunk rebuilds the Harris LUT.
 
     Refresh cadence is runtime data (ControlState), not the config
     constant — the ladder stretches it without a recompile.  ``shed``
     suspends refresh outright; scoring continues against the stale LUT
     (the luvHarris overload mode: degrade quality, never latency).
+    Elementwise, so a lane-stacked state gives one flag per lane.
     """
-    with jax.named_scope("lut_refresh"):
-        do_refresh = (
-            ((state.chunk_idx + 1) % state.ctrl.lut_every) == 0
-        ) & jnp.logical_not(state.ctrl.shed)
-        lut = jax.lax.cond(
-            do_refresh,
-            lambda s: harris_mod.harris_response(
-                s,
-                sobel_size=cfg.sobel_size,
-                window_size=cfg.window_size,
-                k=cfg.harris_k,
-            ),
-            lambda s: lut,
-            surface,
+    return (
+        ((state.chunk_idx + 1) % state.ctrl.lut_every) == 0
+    ) & jnp.logical_not(state.ctrl.shed)
+
+
+def _harris(cfg, surface):
+    return harris_mod.harris_response(
+        surface,
+        sobel_size=cfg.sobel_size,
+        window_size=cfg.window_size,
+        k=cfg.harris_k,
+    )
+
+
+def _refresh_lut(cfg, state: DetectorState, surface, lut):
+    """Periodic Harris LUT rebuild; returns (lut, due).
+
+    Unbatched it is a ``lax.cond``.  Under ``vmap`` a cond on a batched
+    flag would become a select that runs the Harris for every lane, so
+    its batching rule is ``refresh_luts`` instead: the Harris runs for the
+    due lanes only, batched to their count.
+    """
+    @jax.custom_batching.custom_vmap
+    def refresh(surface, lut, due):
+        return jax.lax.cond(
+            due, lambda s: _harris(cfg, s), lambda s: lut, surface
         )
-    return lut, do_refresh
+
+    @refresh.def_vmap
+    def refresh_lanes(axis_size, in_batched, surface, lut, due):
+        surface, lut, due = (
+            x if batched else jnp.broadcast_to(x, (axis_size,) + x.shape)
+            for x, batched in zip((surface, lut, due), in_batched)
+        )
+        return refresh_luts(cfg, surface, lut, due), True
+
+    with jax.named_scope("lut_refresh"):
+        due = lut_due(state)
+        return refresh(surface, lut, due), due
+
+
+def refresh_ladder(lanes: int) -> tuple[int, ...]:
+    """The batch sizes ``refresh_luts`` runs the Harris at over ``lanes``
+    stacked lanes, ascending: 0, then ``lanes``, ``lanes // 2``,
+    ``lanes // 4``, ... down to ``max(1, lanes // 16)``."""
+    least = max(1, lanes // 16)
+    sizes = {0}
+    while lanes >= least:
+        sizes.add(lanes)
+        lanes //= 2
+    return tuple(sorted(sizes))
+
+
+def refresh_luts(cfg, surfaces, luts, due):
+    """Harris LUT rebuild of the lanes flagged ``due`` in lane-stacked
+    ``surfaces`` / ``luts`` (leading lane axis); the other lanes keep
+    their LUT.
+
+    The count of due lanes picks, through ``lax.switch``, the smallest
+    size of ``refresh_ladder`` that holds them: none; a batch of that many
+    lanes gathered by ``jnp.nonzero`` (padded with an out-of-range index,
+    whose scatter is dropped); or every lane, with no gather.  Each lane's
+    response is the unbatched one's.
+    """
+    lanes = due.shape[0]
+    ladder = refresh_ladder(lanes)
+    harris = jax.vmap(functools.partial(_harris, cfg))
+
+    def none(surfaces, luts, due):
+        return luts
+
+    def every(surfaces, luts, due):
+        return jnp.where(due[:, None, None], harris(surfaces), luts)
+
+    def batch(size):
+        def run(surfaces, luts, due):
+            idx = jnp.nonzero(due, size=size, fill_value=lanes)[0]
+            fresh = harris(jnp.take(surfaces, idx, axis=0, mode="clip"))
+            return luts.at[idx].set(fresh, mode="drop")
+        return run
+
+    branches = [none] + [batch(s) for s in ladder[1:-1]] + [every]
+    with jax.named_scope("lut_refresh"):
+        n = jnp.sum(due.astype(jnp.int32))
+        which = jnp.sum((jnp.asarray(ladder) < n).astype(jnp.int32))
+        return jax.lax.switch(which, branches, surfaces, luts, due)
 
 
 def detector_step(
@@ -529,6 +603,7 @@ def detector_step(
     block for the single VMEM-resident Pallas kernel (property-tested
     bit-exact); the DVFS pick, accumulators, and LUT refresh are shared
     code either way, so every serving path gets the fusion unchanged.
+    Vmapped, the LUT refresh runs for the due lanes only (``refresh_luts``).
     """
     if cfg.backend == "pallas_fused":
         return _detector_step_fused(cfg, state, chunk)
@@ -564,8 +639,8 @@ def detector_step(
             -jnp.inf,
         ).astype(jnp.float32)
 
-    lut, do_refresh = _refresh_lut(cfg, state, surface, lut)
-    lut_ready = lut_ready | do_refresh
+    lut, due = _refresh_lut(cfg, state, surface, lut)
+    lut_ready = lut_ready | due
 
     new_state = DetectorState(
         surface=surface,
@@ -631,8 +706,8 @@ def _detector_step_fused(
         scores = jnp.where(lut_ready, raw_scores,
                            -jnp.inf).astype(jnp.float32)
 
-    lut, do_refresh = _refresh_lut(cfg, state, surface, lut)
-    lut_ready = lut_ready | do_refresh
+    lut, due = _refresh_lut(cfg, state, surface, lut)
+    lut_ready = lut_ready | due
 
     new_state = DetectorState(
         surface=surface,

@@ -114,6 +114,9 @@ POOL_STATS = {
     "chunk_distribute_wait_s": "chunk-seconds from fetch to the lane's "
                                "result queue",
     "chunk_handoff_wait_s": "chunk-seconds from the result queue to poll",
+    "lut_refreshes_due": "lane LUT refreshes due in executed rounds",
+    "lut_refresh_lane_runs": "lane Harris runs of the executors' refresh "
+                             "batches (the ladder size per round)",
     "buckets": "per-bucket sub-table (see bucket keys)",
 }
 

@@ -94,6 +94,7 @@ system are unchanged from PR 3/4 — see the class docstrings below and
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import queue
 import threading
@@ -134,12 +135,29 @@ def _mask_tree(active, new_tree, old_tree):
     return jax.tree.map(sel, new_tree, old_tree)
 
 
+def _fold_round(tcfg, states, chunk, mask):
+    """One pool round: the vmapped step, then the mask select.  Returns
+    (states, outs).
+
+    The vmapped step refreshes the LUTs of the lanes due only, batched to
+    their count (``state.refresh_luts``).  A lane masked out is shed for
+    the step: its refresh is not due, and the select drops its new state,
+    the changed knob with it."""
+    ctrl = states.ctrl._replace(shed=states.ctrl.shed | ~mask)
+    new, outs = jax.vmap(
+        lambda s, c: state_mod.detector_step(tcfg, s, c)
+    )(states._replace(ctrl=ctrl), chunk)
+    with jax.named_scope("mask_select"):
+        return _mask_tree(mask, new, states), outs
+
+
 class _Lane:
     """Host-side bookkeeping for one pool slot."""
 
     __slots__ = ("bucket", "buf_xy", "buf_ts", "base", "results", "n_events",
                  "n_chunks", "kept_total", "energy_pj", "latency_ns",
-                 "vdd_trace", "events_folded", "migrations", "migration_log",
+                 "vdd_trace", "events_folded", "chunks_folded",
+                 "migrations", "migration_log",
                  "r_win", "r_cur", "r_p1", "r_p2",
                  "qos", "tier", "knob_lut_every", "knob_vdd_cap",
                  "knob_shed", "shed_events", "gen", "obs_cache",
@@ -168,6 +186,7 @@ class _Lane:
         self.latency_ns = 0.0
         self.vdd_trace: list[float] = []
         self.events_folded = 0          # events consumed by executed rounds
+        self.chunks_folded = 0          # their chunks: the device chunk_idx
         self.migrations = 0             # bucket moves applied to this lane
         # (events_folded, from_bucket, to_bucket) per applied migration —
         # the replay oracle: a StreamingDetector fed the same stream and
@@ -220,15 +239,19 @@ class _Lane:
 
 class _Round:
     """One collected pump round (host arrays, lane-stacked) for a bucket,
-    with its collect time and how many chunks and events it holds."""
+    with its collect time, how many chunks and events it holds, and its
+    LUT refreshes: the lanes due, and the lanes the executor's refresh
+    batch runs the Harris on."""
 
     __slots__ = ("xy", "ts", "valid", "mask", "n_valid", "t", "n_chunks",
-                 "n_events")
+                 "n_events", "lut_due", "lut_runs")
 
-    def __init__(self, xy, ts, valid, mask, n_valid, t, n_chunks, n_events):
+    def __init__(self, xy, ts, valid, mask, n_valid, t, n_chunks, n_events,
+                 lut_due, lut_runs):
         self.xy, self.ts, self.valid = xy, ts, valid
         self.mask, self.n_valid = mask, n_valid
         self.t, self.n_chunks, self.n_events = t, n_chunks, n_events
+        self.lut_due, self.lut_runs = lut_due, lut_runs
 
 
 class _StagedBlock:
@@ -391,6 +414,11 @@ class PoolRuntime:
             sharding_mod.lane_padded_capacity(capacity, self._mesh)
             if self._mesh is not None else capacity
         )
+        # the executors' LUT refresh batches (``refresh_luts``): one ladder
+        # per shard of the lane axis, as ``shard_map`` hands each its lanes
+        self._shards = (int(self._mesh.devices.size)
+                        if self._mesh is not None else 1)
+        self._lut_ladder = state_mod.refresh_ladder(self._phys // self._shards)
 
         # Unsharded pools commit every executor-carried array to one
         # device sharding, as the sharded path commits to its mesh: staged
@@ -585,6 +613,9 @@ class PoolRuntime:
         self._m_chunk_fetch = ctr("chunk_fetch_wait_s")
         self._m_chunk_distribute = ctr("chunk_distribute_wait_s")
         self._m_chunk_handoff = ctr("chunk_handoff_wait_s")
+        # LUT refreshes of executed rounds, counted from the host mirrors
+        self._m_lut_due = ctr("lut_refreshes_due")
+        self._m_lut_runs = ctr("lut_refresh_lane_runs")
 
         def per_bucket(metric):
             return {b: metric.labels(bucket=b) for b in buckets}
@@ -678,8 +709,8 @@ class PoolRuntime:
         return states_spec, ring_spec, out_shardings
 
     def _build_executor(self, bucket: int):
-        """Jitted K-round block: ``lax.scan`` of (vmapped step + mask select
-        + ring push) over ``ring_rounds`` rounds.  Padded rounds are skipped
+        """Jitted K-round block: ``lax.scan`` of (``_fold_round`` + ring
+        push) over ``ring_rounds`` rounds.  Padded rounds are skipped
         by a round-level ``lax.cond`` — block occupancy is data, so this
         compiles exactly once per bucket (the compile-count witness).  When
         a mesh is configured, the whole block runs under ``shard_map`` with
@@ -697,11 +728,7 @@ class PoolRuntime:
                 chunk, m, nv, act = xs
 
                 def real(states, ring):
-                    new_states, outs = jax.vmap(
-                        lambda s, c: state_mod.detector_step(tcfg, s, c)
-                    )(states, chunk)
-                    with jax.named_scope("mask_select"):
-                        states = _mask_tree(m, new_states, states)
+                    states, outs = _fold_round(tcfg, states, chunk, m)
                     with jax.named_scope("ring_push"):
                         ring = push(ring, outs, m, nv, act)
                     return states, ring
@@ -737,8 +764,8 @@ class PoolRuntime:
     def _build_single_executor(self, bucket: int):
         """Jitted 1-round block: the H2D fast path for sparse arrivals.
 
-        Same math as one active row of the K-block (vmapped step + mask
-        select + ring push), but the input shapes drop the leading K axis —
+        Same math as one active row of the K-block (``_fold_round`` + ring
+        push), but the input shapes drop the leading K axis —
         a block with exactly one ready round uploads ``(phys, chunk)``
         bytes instead of ``(K, phys, chunk)``, so a trickle of events no
         longer pays K rounds of padding per dispatch.  The price is a
@@ -749,11 +776,7 @@ class PoolRuntime:
         push = self._ring_push_fn(bucket)
 
         def single(states, ring, chunk, mask, n_valid):
-            new_states, outs = jax.vmap(
-                lambda s, c: state_mod.detector_step(tcfg, s, c)
-            )(states, chunk)
-            with jax.named_scope("mask_select"):
-                states = _mask_tree(mask, new_states, states)
+            states, outs = _fold_round(tcfg, states, chunk, mask)
             with jax.named_scope("ring_push"):
                 ring = push(ring, outs, mask, n_valid, jnp.bool_(True))
             return states, ring
@@ -1723,6 +1746,10 @@ class PoolRuntime:
                 "chunk_distribute_wait_s": float(
                     self._m_chunk_distribute.value()),
                 "chunk_handoff_wait_s": float(self._m_chunk_handoff.value()),
+                # the executors' LUT refreshes: lanes due, and the lanes
+                # the refresh batches ran the Harris on
+                "lut_refreshes_due": self._m_lut_due.value(),
+                "lut_refresh_lane_runs": self._m_lut_runs.value(),
                 "buckets": {
                     b: {
                         "lanes": sum(
@@ -1869,11 +1896,16 @@ class PoolRuntime:
         valid = np.zeros((self._phys, bucket), bool)
         mask = np.zeros((self._phys,), bool)
         n_valid = np.zeros((self._phys,), np.int32)
+        due = np.zeros((self._phys,), bool)
         t = obs_mod.timer()
         fed_sum = 0.0          # feed times of the chunks' last events
         n_events = 0
         for lane, n in ready:
             ln = self._lanes[lane]
+            # state.lut_due from the host mirrors of chunk_idx and ctrl
+            ln.chunks_folded += 1
+            due[lane] = (ln.chunks_folded % int(self._ctrl_lut[lane]) == 0
+                         and not self._ctrl_shed[lane])
             last = ln.fed_cum - int(ln.buf_ts.size) + n
             while ln.feed_times[0][0] < last:
                 ln.feed_times.popleft()
@@ -1894,7 +1926,11 @@ class PoolRuntime:
             ln.events_folded += n
             ln.gen += 1           # backlog changed
         self._m_chunk_buffer.inc(len(ready) * t - fed_sum)
-        return _Round(xy, ts, valid, mask, n_valid, t, len(ready), n_events)
+        per_shard = due.reshape(self._shards, -1).sum(axis=1)
+        runs = sum(self._lut_ladder[bisect.bisect_left(self._lut_ladder, n)]
+                   for n in per_shard.tolist())
+        return _Round(xy, ts, valid, mask, n_valid, t, len(ready), n_events,
+                      int(per_shard.sum()), runs)
 
     def _stage_block(self, bucket: int, rounds: list, *,
                      stage_ahead: bool = False) -> _StagedBlock:
@@ -1973,6 +2009,8 @@ class PoolRuntime:
                 )
                 self._m_h2d_slots[bucket].inc(k * self._phys * bucket)
             self._m_h2d_valid[bucket].inc(blk.n_valid_sum)
+            self._m_lut_due.inc(sum(r.lut_due for r in rounds))
+            self._m_lut_runs.inc(sum(r.lut_runs for r in rounds))
             self._m_stages.inc()
             self._m_stage_s.inc(obs_mod.timer() - t0)
         if stage_ahead and self._pass_dispatches > 0:
