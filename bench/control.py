@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
-"""The control of the ``correct`` comparison: the plain reference, put in
-the program's place and computed one precision below the configuration's
-float32 (bfloat16 image, kernels and intermediates, float32 sums), must
-fail it.
+"""The control of the ``correct`` comparison: the configuration's reference
+module (``bench/reference.py`` where it names none), put in the program's
+place and computed one precision below the configuration's float32
+(bfloat16 image, kernels and intermediates, float32 sums), must fail it.
 
     python3 bench/control.py --workload <cell> --seeds 1,2,3 [--seconds s]
 
@@ -26,7 +26,7 @@ sys.path[:0] = [str(ROOT)]
 
 import numpy as np  # noqa: E402
 
-from bench import harness, reference  # noqa: E402
+from bench import harness  # noqa: E402
 from bench.traffic import OpenSchedule  # noqa: E402
 
 
@@ -35,27 +35,30 @@ def readings(bench: harness.Bench, workload: str, seed: int,
              config_override: dict | None = None,
              lane_events: int | None = None) -> dict:
     """The compared numbers of ``precision``'s reference in the program's
-    place, against the float64 reference, over the sampled lanes."""
+    place, against the float64 reference, over the sampled lanes; the
+    reference is the module the configuration names."""
     wl = bench.workload(workload)
     config = {**bench.config(wl["config"]), **(config_override or {})}
+    ref = bench.reference_of(config)
     cell = bench.cell(workload)
     mix = bench.mix(wl["traffic"])
     src = bench.kind(mix["kind"]).build(mix, cell, config, seed, seconds)
     lanes = int(config["capacity"])
     weight = (np.bincount(src.lane, minlength=lanes)
               if isinstance(src, OpenSchedule) else np.ones(lanes))
-    det = reference.Detector.from_config(config)
+    det = ref.Detector.from_config(config)
     worst: dict = {}
     for lane in harness.sample_lanes(weight, int(config["sample_lanes"]),
                                      seed):
         n = (int(np.count_nonzero(src.lane == lane))
              if isinstance(src, OpenSchedule) else int(lane_events))
         xy, ts = harness.lane_stream(src, lane, n)
-        ref = reference.run_lane(xy, ts, det)
-        low = reference.run_lane(xy, ts, det, precision)
-        got = reference.compare_lane(
-            low.scores, low.keep, reference.lane_state(low, det, precision),
-            ref, det, config["limits"]["score_gap"])
+        seed_of_lane = harness.POOL_SEED + lane
+        want = ref.run_lane(xy, ts, det, lane_seed=seed_of_lane)
+        low = ref.run_lane(xy, ts, det, precision, lane_seed=seed_of_lane)
+        got = ref.compare_lane(
+            low.scores, low.keep, ref.lane_state(low, det, precision),
+            want, det, config["limits"]["score_gap"])
         for k, v in got.items():
             worst[k] = max(worst.get(k, 0), v)
     return worst

@@ -2,7 +2,9 @@
 
 Everything a cell needs is found by name from ``BENCHMARK.json``:
 
-- its configuration ``bench/configs/<config>.json`` (the deployment);
+- its configuration ``bench/configs/<config>.json`` (the deployment),
+  which may name its ``reference``, the module ``bench/<reference>.py``
+  that decides ``correct`` (``bench/reference.py`` where it names none);
 - its own numbers ``bench/cells/<cell>.json`` (a fixed rate, for an open
   loop);
 - its traffic mix ``bench/traffic/<traffic>.json``, whose ``kind`` names
@@ -10,15 +12,24 @@ Everything a cell needs is found by name from ``BENCHMARK.json``:
 - a reader ``bench/metrics/<metric>.py`` for every metric it reports (a
   metric ``x.open`` or ``x.sat`` is read by ``x.py``).
 
-A run builds the pool (``DetectorPool`` at the program's defaults, every
-lane connected), warms the cell's shapes, then three threads drive it for
-the window: a generator (open loop: events fed at their scheduled creation
-times, whatever the pool does; closed loop: each lane topped up to its
-chunks in flight), the application's ``pump()`` loop, and a poller that
-calls ``poll(lane, wait=False)`` for lanes with a full chunk outstanding.
+A run builds the pool (``DetectorPool`` at the program's defaults, lane
+``l`` seeded ``POOL_SEED + l``, every lane connected), warms the cell's
+shapes, then three threads drive it for the window: a generator (open
+loop: events fed at their scheduled creation times, whatever the pool
+does; closed loop: each lane topped up to its chunks in flight), the
+application's ``pump()`` loop, and a poller that calls ``poll(lane,
+wait=False)`` for lanes with a full chunk outstanding.
 After the window the poller keeps draining until every full chunk fed has
 come back (at most ``DRAIN_S`` past the close); sampled lanes are then
-compared with ``bench.reference``.
+compared with the configuration's reference.
+
+A reference module has ``Detector.from_config(config)``;
+``run_lane(xy, ts, det, precision="float64", *, lane_seed)``, the fold of
+one lane's whole chunks given the seed the pool gave that lane;
+``lane_state(result, det, precision)``, the lane's final state as the
+program keeps it; and ``compare_lane(scores, keep, state, result, det,
+score_limit)``, the numbers ``correct`` compares.  ``bench/control.py``
+runs it at ``precision="bfloat16"`` in the program's place.
 """
 from __future__ import annotations
 
@@ -43,6 +54,8 @@ TS_ORIGIN_US = 1_000_000       # first scheduled event's timestamp
 DRAIN_S = 60.0                 # wait for late results past the close
 FLAT_SHARE = 0.005             # a flat backlog grows by less than this share
                                # of the chunks offered in the sweep's window
+POOL_SEED = 0                  # lane l's state is seeded POOL_SEED + l
+REFERENCE_API = ("Detector", "run_lane", "lane_state", "compare_lane")
 
 
 # -- finding a cell's files ---------------------------------------------------
@@ -82,6 +95,21 @@ class Bench:
             "bench_" + "_".join(p.replace(".", "_") for p in parts), path)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
+        return mod
+
+    def reference_of(self, config: dict):
+        """The module that decides ``correct`` for ``config``:
+        ``bench/<reference>.py`` where the configuration names one, else
+        ``bench.reference``."""
+        name = config.get("reference")
+        if name is None:
+            return reference
+        if not isinstance(name, str) or not name.isidentifier():
+            raise ValueError(f"reference {name!r} is not a module name")
+        mod = self._module("bench", f"{name}.py")
+        missing = [a for a in REFERENCE_API if not hasattr(mod, a)]
+        if missing:
+            raise ValueError(f"bench/{name}.py lacks {', '.join(missing)}")
         return mod
 
     def kind(self, kind: str):
@@ -298,10 +326,14 @@ class Options:
 
 
 def pipeline_config(config: dict):
+    from repro.core.dvfs import DvfsConfig
     from repro.core.pipeline import PipelineConfig
 
+    detector = dict(config["detector"])
+    if "dvfs_cfg" in detector:
+        detector["dvfs_cfg"] = DvfsConfig(**detector["dvfs_cfg"])
     return PipelineConfig(height=config["height"], width=config["width"],
-                          **config["detector"])
+                          **detector)
 
 
 def sample_lanes(weight: np.ndarray, k: int, seed: int) -> tuple:
@@ -359,7 +391,8 @@ def build_pool(config: dict, chips: int):
     from repro.serve import DetectorPool
 
     cfg = pipeline_config(config)
-    pool = DetectorPool(cfg, int(config["capacity"]), shard=chips > 1)
+    pool = DetectorPool(cfg, int(config["capacity"]), seed=POOL_SEED,
+                        shard=chips > 1)
     n = 3 * cfg.chunk
     k = np.arange(n)
     pool.warmup(np.stack([k % cfg.width, (k // 7) % cfg.height], 1),
@@ -379,6 +412,7 @@ def run_cell(bench: Bench, opts: Options, t_start: float, *,
     ``ctx_hook(ctx)`` sees what the metric readers read."""
     wl = bench.workload(opts.workload)
     config = {**bench.config(wl["config"]), **(config_override or {})}
+    ref = bench.reference_of(config)
     cell = {**bench.cell(opts.workload), **(cell_override or {})}
     mix = bench.mix(wl["traffic"])
     chunk = int(config["detector"]["chunk"])
@@ -490,7 +524,7 @@ def run_cell(bench: Bench, opts: Options, t_start: float, *,
 
     if ctx_hook is not None:
         ctx_hook(ctx)
-    checks, bad_chunks = check(config, fleet, states, src, sampled)
+    checks, bad_chunks = check(ref, config, fleet, states, src, sampled)
     lost = checks["lost_chunks"]["value"]
     if is_open:
         attempted = int(due)
@@ -595,14 +629,15 @@ def lane_stream(src, lane: int, n_events: int):
     return xy[0], ts[0] + TS_ORIGIN_US
 
 
-def check(config: dict, fleet: Fleet, states: dict, src, sampled):
+def check(ref, config: dict, fleet: Fleet, states: dict, src, sampled):
     """Every lane: every full chunk fed came back once.  Sampled lanes:
-    what ``poll`` returned and the final state equal the reference."""
+    what ``poll`` returned and the final state equal the reference module
+    ``ref``'s fold of the lane, given the seed the pool gave that lane."""
     c = fleet.chunk
     full = (fleet.fed // c) * c
     lost = int(np.sum(np.maximum(0, full - fleet.ret)) // c)
     extra = int(np.sum(np.maximum(0, fleet.ret - full)) // c)
-    det = reference.Detector.from_config(config)
+    det = ref.Detector.from_config(config)
     worst = {"kept_mismatch": 0, "inf_mismatch": 0, "tos_mismatch": 0,
              "sae_mismatch": 0, "score_gap": 0.0, "lut_gap": 0.0}
     bad_chunks = 0
@@ -612,9 +647,9 @@ def check(config: dict, fleet: Fleet, states: dict, src, sampled):
         scores = np.concatenate([o[0] for o in outs] or [np.zeros(0)])
         kept = np.concatenate([o[1] for o in outs] or [np.zeros(0, bool)])
         xy, ts = lane_stream(src, lane, int(full[lane]))
-        ref = reference.run_lane(xy, ts, det)
-        got = reference.compare_lane(scores, kept, states[lane], ref, det,
-                                     lim["score_gap"])
+        want = ref.run_lane(xy, ts, det, lane_seed=POOL_SEED + lane)
+        got = ref.compare_lane(scores, kept, states[lane], want, det,
+                               lim["score_gap"])
         bad_chunks += got.pop("bad_chunks")
         del got["lost_events"], got["extra_events"]   # counted above
         for k, v in got.items():

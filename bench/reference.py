@@ -143,8 +143,11 @@ def stcf(xy: np.ndarray, ts: np.ndarray, det: Detector):
 
 
 def run_lane(xy: np.ndarray, ts: np.ndarray, det: Detector,
-             precision: str = "float64") -> LaneResult:
-    """Fold one lane's stream (whole chunks only) and score every event."""
+             precision: str = "float64", *, lane_seed: int = 0) -> LaneResult:
+    """Fold one lane's stream (whole chunks only) and score every event.
+    ``lane_seed`` is the seed the pool gave the lane's state: this detector
+    draws nothing (no write errors), so it is unused here; a reference of a
+    configuration that injects them draws them from it."""
     n = (len(ts) // det.chunk) * det.chunk
     xy = np.asarray(xy[:n], np.int64)
     ts = np.asarray(ts[:n], np.int64)

@@ -8,11 +8,12 @@ import sys
 import time
 
 import numpy as np
+import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench import harness
+from bench import harness, reference
 
 CMD = [sys.executable, "bench/run.py", "--workload",
        "gen4-720p-16.replay-sat", "--seed", str(2**31 + 5), "--seconds",
@@ -43,16 +44,41 @@ def read(ctx):
 '''
 
 
+PLANTED_REF = '''
+from bench import reference
+from bench.reference import Detector, compare_lane, lane_state
+
+FOLDS = []      # (lane_seed, precision) of every fold asked for
+
+
+def run_lane(xy, ts, det, precision="float64", *, lane_seed):
+    FOLDS.append((lane_seed, precision))
+    return reference.run_lane(xy, ts, det, precision, lane_seed=lane_seed)
+'''
+
+TINY = {"height": 24, "width": 32, "capacity": 8, "sample_lanes": 4}
+SEED = 2**31 + 4343
+# the cells of the first benchmark, whose reference is the default
+FIRST_CELLS = {"gen4-720p-16.streams-open": ("gen4-720p-16", 1),
+               "davis240-1024.replay-sat": ("davis240-1024", 1),
+               "gen4-720p-16.replay-sat": ("gen4-720p-16", 1)}
+
+
 def _json(p):
     return json.loads(pathlib.Path(p).read_text())
+
+
+def _copy_bench(root: pathlib.Path) -> dict:
+    """A copy of the benchmark's files under ``root``; returns its spec."""
+    shutil.copytree(ROOT / "bench", root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    return _json(ROOT / "BENCHMARK.json")
 
 
 def test_new_files_are_found_without_an_edit(tmp_path):
     """A configuration, a cell, a traffic kind and a per-layer metric added
     as new files and entries run through the harness untouched."""
-    shutil.copytree(ROOT / "bench", tmp_path / "bench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    spec = _json(ROOT / "BENCHMARK.json")
+    spec = _copy_bench(tmp_path)
     base = _json(ROOT / "bench/configs/davis240-1024.json")
     base.update(name="tiny-8", height=24, width=32, capacity=8,
                 sample_lanes=2)
@@ -91,6 +117,189 @@ def test_new_files_are_found_without_an_edit(tmp_path):
     assert d["pump_stages"] > 0
     got = bench.reader("rounds_per_dispatch.open").read(seen[0])
     assert got == d["rounds_executed"] / d["pump_stages"] >= 1
+
+
+def _planted_bench(root: pathlib.Path) -> harness.Bench:
+    """A benchmark under ``root`` with a cell ``tiny-ref.streams-open``
+    whose configuration names the planted reference ``planted_ref``, added
+    as new files and appended ``BENCHMARK.json`` entries only."""
+    spec = _copy_bench(root)
+    base = _json(ROOT / "bench/configs/gen4-720p-16.json")
+    base.update(TINY, name="tiny-ref", reference="planted_ref")
+    (root / "bench/configs/tiny-ref.json").write_text(json.dumps(base))
+    (root / "bench/planted_ref.py").write_text(PLANTED_REF)
+    (root / "bench/cells/tiny-ref.streams-open.json").write_text(
+        json.dumps({"rate_eps": 16384}))
+    spec["configs"].append({"name": "tiny-ref", "source": "test",
+                            "file": "bench/configs/tiny-ref.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "tiny-ref.streams-open",
+                              "config": "tiny-ref", "traffic": "streams-open",
+                              "chips": 1, "why": "test"})
+    spec["end_to_end"][0]["workloads"].append("tiny-ref.streams-open")
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return harness.Bench(root)
+
+
+def _record_reference_and_lanes(monkeypatch) -> tuple:
+    """Record every module ``Bench.reference_of`` returns and every lane
+    sample ``harness.sample_lanes`` draws."""
+    refs, sampled = [], []
+    real_ref, real_sample = harness.Bench.reference_of, harness.sample_lanes
+
+    def reference_of(self, config):
+        refs.append(real_ref(self, config))
+        return refs[-1]
+
+    def sample_lanes(*args):
+        sampled.append(real_sample(*args))
+        return sampled[-1]
+
+    monkeypatch.setattr(harness.Bench, "reference_of", reference_of)
+    monkeypatch.setattr(harness, "sample_lanes", sample_lanes)
+    return refs, sampled
+
+
+def test_a_configuration_names_its_reference(tmp_path, monkeypatch):
+    """A configuration that names its own reference module runs as new
+    files and appended entries: ``check`` folds with that module, given
+    each sampled lane's seed."""
+    bench = _planted_bench(tmp_path)
+    refs, sampled = _record_reference_and_lanes(monkeypatch)
+    opts = harness.Options("tiny-ref.streams-open", SEED, 1.0, drain_s=5.0)
+    out = harness.run_cell(bench, opts, time.perf_counter())
+    assert out["correct"], out["checks"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    [planted] = refs
+    assert planted is not reference
+    assert planted.FOLDS == [(harness.POOL_SEED + lane, "float64")
+                             for lane in sampled[0]]
+
+
+def test_the_control_folds_with_the_named_reference(tmp_path, monkeypatch):
+    """``bench/control.py`` puts the configuration's own reference in the
+    program's place: it folds each sampled lane with the planted module,
+    given the lane's seed, in float64 and in bfloat16, and the bfloat16
+    fold fails the score limit."""
+    from bench import control
+
+    bench = _planted_bench(tmp_path)
+    refs, sampled = _record_reference_and_lanes(monkeypatch)
+    got = control.readings(bench, "tiny-ref.streams-open", SEED, 1.0)
+    [planted] = refs
+    assert planted is not reference
+    assert planted.FOLDS == [(harness.POOL_SEED + lane, p)
+                             for lane in sampled[0]
+                             for p in ("float64", "bfloat16")]
+    limits = bench.config("tiny-ref")["limits"]
+    assert got["score_gap"] > limits["score_gap"]
+
+
+def test_write_errors_fail_the_plain_reference():
+    """The guard behind the reference seam: a fleet whose TOS macro runs at
+    0.6 V with its write errors injected, checked by the plain reference
+    (which models no errors), is not correct."""
+    bench = harness.Bench(ROOT)
+    det = {**bench.config("gen4-720p-16")["detector"], "vdd": 0.6,
+           "inject_ber": True}
+    opts = harness.Options("gen4-720p-16.streams-open", SEED, 1.0,
+                           drain_s=5.0)
+    out = harness.run_cell(bench, opts, time.perf_counter(),
+                           config_override={**TINY, "detector": det},
+                           cell_override={"rate_eps": 16384})
+    assert not out["correct"]
+    assert out["checks"]["tos_mismatch"]["value"] > 0
+
+
+def _stub_pool(monkeypatch) -> list:
+    """Replace ``DetectorPool`` by a stub; returns the list its calls
+    (positional and keyword arguments) are recorded in."""
+    import repro.serve
+
+    calls = []
+
+    class Stub:
+        def __init__(self, *args, **kwargs):
+            calls.append((args, kwargs))
+
+        def warmup(self, xy, ts):
+            pass
+
+        def connect(self):
+            return 0
+
+    monkeypatch.setattr(repro.serve, "DetectorPool", Stub)
+    return calls
+
+
+@pytest.mark.parametrize("cell", sorted(FIRST_CELLS))
+def test_first_cells_keep_the_plain_reference_and_pool(monkeypatch, cell):
+    """The first cells' configurations name no reference:
+    ``bench.reference`` checks them, and the pool is built as before
+    (``seed=0`` is ``DetectorPool``'s default)."""
+    bench = harness.Bench(ROOT)
+    name, chips = FIRST_CELLS[cell]
+    wl = bench.workload(cell)
+    assert (wl["config"], wl["chips"]) == (name, chips)
+    config = bench.config(name)
+    assert bench.reference_of(config) is reference
+    calls = _stub_pool(monkeypatch)
+    harness.build_pool(config, chips)
+    assert calls == [((harness.pipeline_config(config), config["capacity"]),
+                      {"seed": 0, "shard": chips > 1})]
+
+
+def test_a_named_reference_must_exist_and_fold(tmp_path):
+    (tmp_path / "bench").mkdir()
+    (tmp_path / "BENCHMARK.json").write_text("{}")
+    (tmp_path / "bench/half_ref.py").write_text("def run_lane(): pass\n")
+    bench = harness.Bench(tmp_path)
+    with pytest.raises(FileNotFoundError):
+        bench.reference_of({"reference": "no_such_ref"})
+    with pytest.raises(ValueError, match="Detector, lane_state, compare_lane"):
+        bench.reference_of({"reference": "half_ref"})
+    with pytest.raises(ValueError, match="not a module name"):
+        bench.reference_of({"reference": "../reference"})
+
+
+def _davis(**detector) -> dict:
+    config = _json(ROOT / "bench/configs/davis240-1024.json")
+    config["detector"] = {**config["detector"], **detector}
+    return config
+
+
+def test_dvfs_cfg_is_built():
+    from repro.core.dvfs import DvfsConfig
+
+    cfg = harness.pipeline_config(_davis(
+        dvfs=True, dvfs_online=True,
+        dvfs_cfg={"tw_us": 8000, "vdd_floor": 0.6}))
+    assert cfg.dvfs_cfg == DvfsConfig(tw_us=8000, vdd_floor=0.6)
+    assert cfg.dvfs_cfg.half_us == 4000
+
+
+@pytest.mark.parametrize("detector", [{"no_such_key": 1},
+                                      {"dvfs_cfg": {"no_such_key": 1}}])
+def test_unknown_detector_keys_raise(detector):
+    with pytest.raises(TypeError, match="no_such_key"):
+        harness.pipeline_config(_davis(**detector))
+
+
+def test_lane_seed_is_the_lane_id():
+    """What ``lane_seed`` rests on: a pool built by the harness gives lane
+    ``l`` the random key of ``POOL_SEED + l`` once it is connected."""
+    import jax
+
+    config = {**_json(ROOT / "bench/configs/davis240-1024.json"), **TINY}
+    pool = harness.build_pool(config, 1)
+    try:
+        keys = np.asarray(pool._states.key)
+    finally:
+        pool.close()
+    want = [np.asarray(jax.random.PRNGKey(harness.POOL_SEED + lane))
+            for lane in range(config["capacity"])]
+    assert harness.POOL_SEED == 0
+    np.testing.assert_array_equal(keys, np.stack(want))
 
 
 def test_window_deltas_cover_every_number():
