@@ -625,7 +625,8 @@ def detector_step(
     with jax.named_scope("tos_update"):
         surface = update(surface, chunk.xy, keep)
 
-        if cfg.inject_ber:
+    if cfg.inject_ber:
+        with jax.named_scope("ber_inject"):
             key, sub = jax.random.split(key)
             surface = ber_mod.inject_write_errors_at(sub, surface, ber_c)
 
@@ -686,7 +687,7 @@ def _detector_step_fused(
     # oracle via ber.write_error_bits), xor/decode applied in-kernel.
     bits = None
     if cfg.inject_ber:
-        with jax.named_scope("tos_update"):
+        with jax.named_scope("ber_inject"):
             key, sub = jax.random.split(key)
             bits = ber_mod.write_error_bits(sub, surface.shape, ber_c)
 

@@ -117,6 +117,8 @@ POOL_STATS = {
     "lut_refreshes_due": "lane LUT refreshes due in executed rounds",
     "lut_refresh_lane_runs": "lane Harris runs of the executors' refresh "
                              "batches (the ladder size per round)",
+    "ber_chunks": "chunk results handed back by poll that were folded at a "
+                  "Vdd with write errors (online DVFS only)",
     "buckets": "per-bucket sub-table (see bucket keys)",
 }
 

@@ -161,7 +161,7 @@ class _Lane:
                  "r_win", "r_cur", "r_p1", "r_p2",
                  "qos", "tier", "knob_lut_every", "knob_vdd_cap",
                  "knob_shed", "shed_events", "gen", "obs_cache",
-                 "fed_cum", "feed_times", "res_n", "res_tsum")
+                 "fed_cum", "feed_times", "res_n", "res_tsum", "res_ber")
 
     def __init__(self, bucket: int, *, qos: str = "standard",
                  lut_every: int = 1, vdd_cap: int = 0):
@@ -210,11 +210,13 @@ class _Lane:
         # after the slab, its feed time) per slab still in the buffer, so
         # a collect finds when its chunk's last event was fed; ``res_n``
         # results wait in ``results``, distributed at times summing to
-        # ``res_tsum``.
+        # ``res_tsum``, ``res_ber`` of them folded at a Vdd with write
+        # errors.
         self.fed_cum = 0
         self.feed_times: collections.deque = collections.deque()
         self.res_n = 0
         self.res_tsum = 0.0
+        self.res_ber = 0
 
     def rate_update(self, ts: np.ndarray, half: int) -> None:
         """Fold one time-sorted slab into the rate twin (vectorized; only
@@ -616,6 +618,9 @@ class PoolRuntime:
         # LUT refreshes of executed rounds, counted from the host mirrors
         self._m_lut_due = ctr("lut_refreshes_due")
         self._m_lut_runs = ctr("lut_refresh_lane_runs")
+        # chunks handed back whose operating point writes with errors, from
+        # the vdd_idx the readout fetches anyway (online DVFS only)
+        self._m_ber_chunks = ctr("ber_chunks")
 
         def per_bucket(metric):
             return {b: metric.labels(bucket=b) for b in buckets}
@@ -1216,7 +1221,8 @@ class PoolRuntime:
         ln.results.clear()
         self._m_chunk_handoff.inc(ln.res_n * obs_mod.timer() - ln.res_tsum)
         self._m_chunks_returned.inc(ln.res_n)
-        ln.res_n, ln.res_tsum = 0, 0.0
+        self._m_ber_chunks.inc(ln.res_ber)
+        ln.res_n, ln.res_tsum, ln.res_ber = 0, 0.0, 0
         return scores, kept
 
     # -- migration mechanics -------------------------------------------------
@@ -1750,6 +1756,7 @@ class PoolRuntime:
                 # the refresh batches ran the Harris on
                 "lut_refreshes_due": self._m_lut_due.value(),
                 "lut_refresh_lane_runs": self._m_lut_runs.value(),
+                "ber_chunks": self._m_ber_chunks.value(),
                 "buckets": {
                     b: {
                         "lanes": sum(
@@ -2283,6 +2290,7 @@ class PoolRuntime:
         ``device_get`` returned at ``t_fetched``."""
         n_slots = ring.scores.shape[0]
         got: dict = {}            # lane -> chunks handed to it
+        ber_hot = self._tab.ber > 0 if self._online else None
         for slot in state_mod.ring_slot_order(ring.head, ring.count, n_slots):
             for lane in np.flatnonzero(ring.mask[slot]):
                 ln = self._lanes[int(lane)]
@@ -2295,6 +2303,8 @@ class PoolRuntime:
                     online=self._online, tab=self._tab,
                     fixed_vdd=self._cfg.vdd,
                 )
+                if ber_hot is not None:
+                    ln.res_ber += int(ber_hot[ring.vdd_idx[slot, lane]])
                 # copy: a view would pin the whole fetched (R, lanes,
                 # chunk) buffer in the lane queue until the lane polls
                 ln.results.append((

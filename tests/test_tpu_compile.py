@@ -109,10 +109,10 @@ def test_compaction_compiles(one_chip, geometry):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_vmapped_detector_step_jnp_compiles(one_chip):
-    cfg = pipeline.PipelineConfig(height=180, width=240, chunk=CHUNK)
+def _vmapped_step(one_chip, cfg, lanes):
+    """The step as the pool executors vmap it over ``lanes`` lanes,
+    compiled for one described chip."""
     tcfg = pipeline._trace_cfg(cfg)
-    lanes = 64
     one = jax.eval_shape(lambda: state_mod.detector_init(cfg))
     states = jax.tree.map(
         lambda a: _spec(one_chip, (lanes,) + a.shape, a.dtype), one)
@@ -125,5 +125,21 @@ def test_vmapped_detector_step_jnp_compiles(one_chip):
         latency_coef=_spec(one_chip, (lanes,), jnp.float32),
     )
     step = jax.vmap(lambda s, c: state_mod.detector_step(tcfg, s, c))
-    compiled = jax.jit(step).lower(states, chunk).compile()
+    return jax.jit(step).lower(states, chunk).compile()
+
+
+def test_vmapped_detector_step_jnp_compiles(one_chip):
+    cfg = pipeline.PipelineConfig(height=180, width=240, chunk=CHUNK)
+    compiled = _vmapped_step(one_chip, cfg, 64)
     assert compiled.memory_analysis() is not None
+
+
+def test_vmapped_near_threshold_step_compiles(one_chip):
+    """The 0.6 V fleet's step: the online DVFS pick and the write errors'
+    threefry draws over every lane's (180, 240, 5) bits."""
+    from repro.core.dvfs import DvfsConfig
+
+    cfg = pipeline.PipelineConfig(height=180, width=240, chunk=CHUNK,
+                                  dvfs=True, dvfs_online=True,
+                                  inject_ber=True, dvfs_cfg=DvfsConfig())
+    assert "ber_inject" in _vmapped_step(one_chip, cfg, 8).as_text()
